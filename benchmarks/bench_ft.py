@@ -33,10 +33,18 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _tiny_state():
-    import jax.numpy as jnp
-    return {"params": {"w": jnp.arange(12.0).reshape(3, 4),
-                       "b": jnp.ones((4,))},
-            "step": jnp.int32(5)}
+    # numpy, not jnp: this process must stay off the device, because the
+    # kill-and-restart scenario's launcher children need the chip
+    return {"params": {"w": np.arange(12.0, dtype=np.float32).reshape(3, 4),
+                       "b": np.ones((4,), np.float32)},
+            "step": np.int32(5)}
+
+
+def _host_scenarios():
+    """Every scenario that runs in this process; none of them touches a
+    JAX device."""
+    return (_corrupt_recovery_scenarios() + [_producer_raise_scenario()]
+            + _failing_writer_scenarios())
 
 
 def _corrupt_recovery_scenarios():
@@ -168,10 +176,7 @@ def _kill_restart_scenario(fast: bool = True):
 
 def ft_json(fast: bool = True) -> dict:
     """The fault-injection recovery record (see module doc)."""
-    scenarios = []
-    scenarios += _corrupt_recovery_scenarios()
-    scenarios.append(_producer_raise_scenario())
-    scenarios += _failing_writer_scenarios()
+    scenarios = _host_scenarios()
     scenarios.append(_kill_restart_scenario(fast=fast))
     return {"scenarios": scenarios,
             "all_recovered": all(s["recovered"] for s in scenarios)}

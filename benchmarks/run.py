@@ -228,6 +228,7 @@ def main(argv=None) -> None:
     only = set(args.only.split(",")) if args.only else set(benches)
 
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in benches.items():
         if name not in only:
             continue
@@ -236,9 +237,16 @@ def main(argv=None) -> None:
             for row in fn(fast=fast):
                 print(row, flush=True)
         except Exception as e:  # noqa
+            # the other benches still run, but the exit code says one broke
             print(f"{name},0.0,ERROR:{type(e).__name__}:{e}", flush=True)
+            failed.append(name)
         print(f"# {name} done in {time.time()-t0:.1f}s", file=sys.stderr)
+    if failed:
+        print(f"bench(es) raised: {', '.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

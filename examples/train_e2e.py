@@ -33,6 +33,7 @@ from repro.core.config import (E2TrainConfig, Experiment, ModelConfig,
 from repro.data.synthetic import (GaussianImageTask, MarkovLMTask,
                                   make_image_batch, make_lm_batch)
 from repro.ft.checkpoint import latest_step, restore_checkpoint
+from repro.launch.compile_cache import use_compile_cache
 from repro.training.train_step import init_train_state
 from repro.training.trainer import Trainer
 
@@ -69,11 +70,17 @@ def main():
     ap.add_argument("--mesh", type=int, default=0, metavar="N",
                     help="N-way data-parallel mesh over the batch axis "
                          "(0 = single device)")
-    ap.add_argument("--fused-conv", action="store_true",
-                    help="route CNN convs through the fused implicit-GEMM "
-                         "kernels (kernels/conv.py) instead of materialized "
-                         "im2col (cifar_cnn task; DESIGN.md §Kernels)")
+    ap.add_argument("--fused-conv", action="store_const", const=True,
+                    default=None,
+                    help="pin CNN convs to the fused implicit-GEMM kernels "
+                         "(kernels/conv.py); unset, the kernel backend "
+                         "decides (cifar_cnn task; DESIGN.md §Kernels)")
+    ap.add_argument("--deadline-s", type=float, default=0.0,
+                    help="per-step straggler deadline: steps over it arm "
+                         "SMD-style forced drops (0 = off; a step that "
+                         "compiles can take far longer than a warm one)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.mesh > 1 and jax.device_count() < args.mesh:
         raise SystemExit(
             f"--mesh {args.mesh} needs {args.mesh} devices but only "
@@ -125,7 +132,7 @@ def main():
         print(f"mesh: {args.mesh}-way data parallel over {mesh.devices.size} "
               "devices")
     trainer = Trainer(exp, state, make_batch, checkpoint_dir=args.ckpt,
-                      checkpoint_every=50, deadline_s=30.0,
+                      checkpoint_every=50, deadline_s=args.deadline_s,
                       chunk_steps=args.chunk_steps, mesh=mesh)
     hist = trainer.run(args.steps, log_every=10)
     if hist:

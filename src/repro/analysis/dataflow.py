@@ -58,7 +58,7 @@ from typing import Dict, FrozenSet, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax import core as jcore
+from jax.extend.core import ClosedJaxpr, Literal
 
 # dtypes whose presence in a lineage marks a value "narrow-descended"
 NARROW_DTYPES = frozenset({
@@ -200,7 +200,7 @@ class _Interp:
     # -- environment ------------------------------------------------------
 
     def _read(self, env: Dict, atom) -> Prov:
-        if isinstance(atom, jcore.Literal):
+        if isinstance(atom, Literal):
             dt = _dtype_name(getattr(atom.aval, "dtype", "void"))
             nar = frozenset({dt}) if dt in NARROW_DTYPES else frozenset()
             return Prov(narrow=nar, origin="literal" if nar else "")
@@ -227,7 +227,7 @@ class _Interp:
 
     # -- interpretation ---------------------------------------------------
 
-    def run_closed(self, closed: jcore.ClosedJaxpr, in_provs: Sequence[Prov],
+    def run_closed(self, closed: ClosedJaxpr, in_provs: Sequence[Prov],
                    path: str) -> List[Prov]:
         jx = closed.jaxpr
         env: Dict = {}
@@ -279,7 +279,7 @@ class _Interp:
         if prim == "reduce":
             # generic lax.reduce: a sum iff its computation jaxpr adds
             comp = p.get("jaxpr")
-            comp_j = comp.jaxpr if isinstance(comp, jcore.ClosedJaxpr) \
+            comp_j = comp.jaxpr if isinstance(comp, ClosedJaxpr) \
                 else comp
             additive = any(e.primitive.name in _ADDITIVE
                            for e in getattr(comp_j, "eqns", []))
@@ -297,8 +297,8 @@ class _Interp:
         for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):
             if key in p:
                 sub = p[key]
-                closed = sub if isinstance(sub, jcore.ClosedJaxpr) \
-                    else jcore.ClosedJaxpr(sub, ())
+                closed = sub if isinstance(sub, ClosedJaxpr) \
+                    else ClosedJaxpr(sub, ())
                 ops = [self._read(env, a) for a in eqn.invars]
                 outs = self.run_closed(closed, ops, path)
                 for ov, pr in zip(eqn.outvars, outs):
@@ -404,7 +404,7 @@ class _Interp:
         p = eqn.params
         gm = p["grid_mapping"]
         inner = p["jaxpr"]
-        jx = inner.jaxpr if isinstance(inner, jcore.ClosedJaxpr) else inner
+        jx = inner.jaxpr if isinstance(inner, ClosedJaxpr) else inner
         n_in, n_out = gm.num_inputs, gm.num_outputs
         kname = p.get("name", "kernel")
         kpath = f"{path}/pallas:{kname}"
@@ -434,7 +434,7 @@ class _Interp:
             self._bind(env, ov, replace(content, taints=frozenset()), where)
 
 
-def analyze_jaxpr(closed: jcore.ClosedJaxpr,
+def analyze_jaxpr(closed: ClosedJaxpr,
                   name: str = "program") -> DataflowResult:
     """Interpret an already-traced program (see :func:`analyze`)."""
     it = _Interp(name)

@@ -46,13 +46,13 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.extend.core import ClosedJaxpr
 
 from repro.analysis.jaxpr_cost import sub_jaxprs
 
 # primitives that round-trip to the host when executed
 _CALLBACK_MARKERS = ("callback",)
-_CALLBACK_PRIMS = frozenset({"infeed", "outfeed"})
+_CALLBACK_PRIMS = frozenset({"infeed", "outfeed", "debug_print"})
 
 
 @dataclass(frozen=True)
@@ -79,7 +79,7 @@ def _walk_prims(jx, path: str, out: List[Tuple[str, str]]) -> None:
             _walk_prims(sub.jaxpr, f"{path}/{prim}", out)
 
 
-def _all_prims(closed: jcore.ClosedJaxpr, name: str) -> List[Tuple[str, str]]:
+def _all_prims(closed: ClosedJaxpr, name: str) -> List[Tuple[str, str]]:
     out: List[Tuple[str, str]] = []
     _walk_prims(closed.jaxpr, name, out)
     return out

@@ -39,7 +39,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import numpy as np
-from jax import core as jcore
+from jax.extend.core import ClosedJaxpr
 
 SCOPE_RE = re.compile(r"cost:([\w.\-]+)")
 UNATTRIBUTED = ""
@@ -204,14 +204,14 @@ def sub_jaxprs(eqn):
         gm = p["grid_mapping"]
         trips = float(math.prod(gm.grid)) if gm.grid else 1.0
         inner = p["jaxpr"]
-        closed = jcore.ClosedJaxpr(inner, ()) \
-            if not isinstance(inner, jcore.ClosedJaxpr) else inner
+        closed = ClosedJaxpr(inner, ()) \
+            if not isinstance(inner, ClosedJaxpr) else inner
         return [(closed, trips)], False
     for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):
         if key in p:
             sub = p[key]
-            closed = sub if isinstance(sub, jcore.ClosedJaxpr) \
-                else jcore.ClosedJaxpr(sub, ())
+            closed = sub if isinstance(sub, ClosedJaxpr) \
+                else ClosedJaxpr(sub, ())
             return [(closed, 1.0)], False
     return [], False
 
@@ -270,7 +270,7 @@ def _walk(jaxpr, costs: ProgramCosts, scale: float,
             c.out_bytes += scale * _out_bytes(eqn)
 
 
-def walk_jaxpr(closed: jcore.ClosedJaxpr) -> ProgramCosts:
+def walk_jaxpr(closed: ClosedJaxpr) -> ProgramCosts:
     costs = ProgramCosts()
     _walk(closed.jaxpr, costs, 1.0, UNATTRIBUTED)
     return costs

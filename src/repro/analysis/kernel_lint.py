@@ -8,11 +8,14 @@ rules are checked against the grid mapping and the kernel jaxpr
 
 * ``vmem-budget`` — double-buffered input/output blocks plus scratch must
   fit the per-core VMEM budget (16 MiB).
-* ``tile-alignment`` — every block dimension must either span the full
-  array extent or align to the MXU/VPU lattice (last dim % 128,
-  second-to-last % 8).  Sub-tile blocks (scalar thresholds, per-tile
-  statistics smaller than one 8x128 tile) are padding-dominated either way
-  and exempt.
+* ``tile-alignment`` — Mosaic's own block rule: the last two block
+  dimensions must each span the full array extent or align to the
+  (8, 128) tile (rank-1 blocks: full, or a multiple of 128 * 32/bits).
+  Small blocks get no exemption — a ``(1, 1)`` VMEM block of a larger
+  array is refused by the compiler; scalars and per-tile flags belong in
+  SMEM, whose whole-array blocks are exempt.  ``tests/test_tpu_compile.py``
+  compiles the main-path kernels for a described chip and is the
+  authority; this rule is the fast pre-check.
 * ``coverage`` / ``oob-index`` — output BlockSpec index maps, enumerated
   over the full grid, must write every tile of the output lattice exactly
   (an uncovered tile is silent garbage memory) and no input/output index
@@ -36,12 +39,10 @@ from typing import Dict, List, Sequence, Set, Tuple
 import jax
 import numpy as np
 from jax import core as jcore
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 SUBLANE, LANE = 8, 128
-# blocks smaller than one MXU tile (scalars, per-tile stats) are exempt
-# from alignment: the compiler pads them whatever we do.
-_SUBTILE_NUMEL = SUBLANE * LANE
 # coverage enumeration walks the full grid; past this it is skipped (no
 # shipped kernel is near it — a representative registry shape should keep
 # grids small on purpose).
@@ -62,14 +63,26 @@ class LintFinding:
 
 def _kernel_jaxpr(eqn):
     kj = eqn.params["jaxpr"]
-    return kj.jaxpr if isinstance(kj, jcore.ClosedJaxpr) else kj
+    return kj.jaxpr if isinstance(kj, ClosedJaxpr) else kj
 
 
 def _block_shape(bm) -> Tuple[int, ...]:
-    return tuple(1 if d is None else int(d) for d in bm.block_shape)
+    """Block extents; squeezed dimensions (no ``block_size``) count as 1."""
+    return tuple(int(getattr(d, "block_size", 1)) for d in bm.block_shape)
 
 
-def _eval_index_map(cj: jcore.ClosedJaxpr, point: Sequence[int]
+def _full_shape(bm) -> Tuple[int, ...]:
+    return tuple(int(d) for d in bm.array_aval.shape)
+
+
+def _in_smem(bm) -> bool:
+    """A whole-array SMEM operand (scalars, flag vectors): no VMEM, no
+    tiling rule."""
+    from jax.experimental.pallas import tpu as pltpu
+    return bm.block_aval.memory_space == pltpu.SMEM
+
+
+def _eval_index_map(cj: ClosedJaxpr, point: Sequence[int]
                     ) -> Tuple[int, ...]:
     outs = jcore.eval_jaxpr(cj.jaxpr, cj.consts,
                             *[np.int32(p) for p in point])
@@ -86,9 +99,9 @@ def _find_pallas_eqns(jaxpr) -> List:
         for val in eqn.params.values():
             subs = val if isinstance(val, (list, tuple)) else (val,)
             for sub in subs:
-                if isinstance(sub, jcore.ClosedJaxpr):
+                if isinstance(sub, ClosedJaxpr):
                     found.extend(_find_pallas_eqns(sub.jaxpr))
-                elif isinstance(sub, jcore.Jaxpr):
+                elif isinstance(sub, Jaxpr):
                     found.extend(_find_pallas_eqns(sub))
     return found
 
@@ -101,7 +114,9 @@ def _find_pallas_eqns(jaxpr) -> List:
 def _lint_vmem(name: str, gm, kj) -> List[LintFinding]:
     block_bytes = 0.0
     for bm in gm.block_mappings:
-        dt = np.dtype(bm.array_shape_dtype.dtype)
+        if _in_smem(bm):
+            continue
+        dt = np.dtype(bm.array_aval.dtype)
         block_bytes += math.prod(_block_shape(bm)) * dt.itemsize
     scratch_bytes = 0.0
     n_io = gm.num_inputs + gm.num_outputs
@@ -125,13 +140,15 @@ def _lint_alignment(name: str, gm) -> List[LintFinding]:
     findings = []
     for pos, bm in enumerate(gm.block_mappings):
         kind = "in" if pos < gm.num_inputs else "out"
-        bs = _block_shape(bm)
-        full = tuple(int(d) for d in bm.array_shape_dtype.shape)
-        if math.prod(bs) < _SUBTILE_NUMEL:
+        if _in_smem(bm):
             continue
+        bs = _block_shape(bm)
+        full = _full_shape(bm)
+        lane = LANE if len(bs) >= 2 else \
+            LANE * 4 // np.dtype(bm.array_aval.dtype).itemsize
         bad = []
-        if bs[-1] % LANE != 0 and bs[-1] != full[-1]:
-            bad.append(f"last dim {bs[-1]} (want %{LANE} or full {full[-1]})")
+        if bs[-1] % lane != 0 and bs[-1] != full[-1]:
+            bad.append(f"last dim {bs[-1]} (want %{lane} or full {full[-1]})")
         if len(bs) >= 2 and bs[-2] % SUBLANE != 0 and bs[-2] != full[-2]:
             bad.append(f"dim -2 {bs[-2]} (want %{SUBLANE} or full {full[-2]})")
         if bad:
@@ -155,7 +172,7 @@ def _lint_coverage(name: str, gm) -> List[LintFinding]:
         if len(cj.jaxpr.invars) != len(grid):
             continue                       # scalar-prefetch args: skip
         bs = _block_shape(bm)
-        full = tuple(int(d) for d in bm.array_shape_dtype.shape)
+        full = _full_shape(bm)
         nblocks = tuple(-(-f // b) for f, b in zip(full, bs))
         covered: Set[Tuple[int, ...]] = set()
         oob_reported = False
@@ -217,7 +234,7 @@ def _lint_accumulators(name: str, gm, kj) -> List[LintFinding]:
             if e.primitive.name == "eq":
                 lit, pid = None, None
                 for iv in e.invars:
-                    if isinstance(iv, jcore.Literal):
+                    if isinstance(iv, Literal):
                         try:
                             lit = int(iv.val)
                         except (TypeError, ValueError):
@@ -227,7 +244,7 @@ def _lint_accumulators(name: str, gm, kj) -> List[LintFinding]:
                 if pid is not None and lit in guards:
                     guards[lit].add(e.outvars[0])
             elif e.primitive.name == "convert_element_type" \
-                    and not isinstance(e.invars[0], jcore.Literal):
+                    and not isinstance(e.invars[0], Literal):
                 aliases[e.outvars[0]] = e.invars[0]
         gated = {0: False, grid[axis] - 1: False}
         for e in kj.eqns:
@@ -258,7 +275,7 @@ def _lint_accumulators(name: str, gm, kj) -> List[LintFinding]:
 # ---------------------------------------------------------------------------
 
 
-def lint_jaxpr(closed: jcore.ClosedJaxpr, name: str = "kernel"
+def lint_jaxpr(closed: ClosedJaxpr, name: str = "kernel"
                ) -> List[LintFinding]:
     """Lint every pallas_call inside an already-traced program."""
     findings: List[LintFinding] = []

@@ -27,15 +27,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 def make_abstract_mesh(shape, axes):
     """Device-free mesh for spec logic (tests, shape-only planning).
 
-    Current JAX's ``AbstractMesh`` takes ``((name, size), ...)`` pairs;
-    older releases took ``(shape_tuple, axis_names)`` positionally.  Accept
-    the classic ``(shape, axes)`` call and translate.
+    ``AbstractMesh`` takes ``(axis_sizes, axis_names)``, the same order
+    as :class:`jax.sharding.Mesh`'s shape and axes.
     """
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:        # pre-pairs API
-        return AbstractMesh(tuple(shape), tuple(axes))
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 # ---------------------------------------------------------------------------
 # activation-sharding hints (trace-time context, like core.psg.enable)
@@ -102,6 +98,21 @@ def ctx_mesh_axis_size(name: str) -> int:
     if mesh is None or name not in mesh.axis_names:
         return 1
     return mesh.shape[name]
+
+
+def ctx_data_axes(rows: int):
+    """``(mesh, axes)``: the active mesh and its data axes (pod, data) of
+    size > 1 when they split ``rows`` evenly, else ``(mesh, ())`` —
+    ``(None, ())`` when tracing without a mesh."""
+    mesh = getattr(_act, "mesh", None)
+    if mesh is None:
+        return None, ()
+    axes = tuple(a for a in ACT_AXES["batch"]
+                 if a in mesh.axis_names and mesh.shape[a] > 1)
+    size = int(np.prod([mesh.shape[a] for a in axes])) if axes else 1
+    if size == 1 or rows % size:
+        return mesh, ()
+    return mesh, axes
 
 
 def replicate(x):

@@ -74,6 +74,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.psg_matmul import CODE_PRECISION
+
 DEFAULT_BN = 128
 
 
@@ -190,7 +192,7 @@ def _conv_pred_kernel(xm_ref, gm_ref, out_ref, acc, *, k: int, stride: int,
     for t in range(k * k):
         acc[t * c:(t + 1) * c, :] += jnp.dot(
             _tap_window(xm, t, k, stride, ho, wo).T, gm,
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32, precision=CODE_PRECISION)
 
     @pl.when(b == n_b - 1)
     def _finish():
@@ -199,10 +201,13 @@ def _conv_pred_kernel(xm_ref, gm_ref, out_ref, acc, *, k: int, stride: int,
 
 def _conv_grad_w_kernel(xm_ref, gm_ref, xq_ref, gq_ref, tau_ref,
                         out_ref, stats_ref, acc_msb, acc_full,
-                        *, k: int, stride: int, ho: int, wo: int, n_b: int):
+                        *, k: int, stride: int, ho: int, wo: int, n_j: int,
+                        n_b: int):
     """Fused PSG weight grad: both accumulators carried across images,
-    tau-gated per (tap, dout-tile) on the last reduction step."""
-    b = pl.program_id(1)
+    tau-gated per (tap, dout-tile) on the last reduction step.  ``tau`` is
+    a (1,) SMEM scalar; the per-tile fallback flags go to a flat SMEM
+    vector, entry ``t * n_j + j`` for tap ``t`` of dout tile ``j``."""
+    j, b = pl.program_id(0), pl.program_id(1)
 
     @pl.when(b == 0)
     def _init():
@@ -217,21 +222,22 @@ def _conv_grad_w_kernel(xm_ref, gm_ref, xq_ref, gq_ref, tau_ref,
     for t in range(k * k):
         acc_msb[t * c:(t + 1) * c, :] += jnp.dot(
             _tap_window(xm, t, k, stride, ho, wo).T, gm,
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32, precision=CODE_PRECISION)
         acc_full[t * c:(t + 1) * c, :] += jnp.dot(
             _tap_window(xq, t, k, stride, ho, wo).T, gq,
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32, precision=CODE_PRECISION)
 
     @pl.when(b == n_b - 1)
     def _finish():
-        tau = tau_ref[0, 0]
+        tau = tau_ref[0]
         for t in range(k * k):
             g_msb = acc_msb[t * c:(t + 1) * c, :]
             g_full = acc_full[t * c:(t + 1) * c, :]
             conf = jnp.abs(g_msb) >= tau
             out_ref[t * c:(t + 1) * c, :] = jnp.where(
                 conf, jnp.sign(g_msb), jnp.sign(g_full)).astype(jnp.int8)
-            stats_ref[t, 0] = jnp.logical_not(jnp.all(conf)).astype(jnp.int32)
+            stats_ref[t * n_j + j] = jnp.logical_not(
+                jnp.all(conf)).astype(jnp.int32)
 
 
 def _pad_dout(a: jnp.ndarray, bn: int) -> jnp.ndarray:
@@ -356,28 +362,28 @@ def conv_grad_w_pallas(xm: jnp.ndarray, gm: jnp.ndarray,
     n_j = doutp // bn_
     out, stats = pl.pallas_call(
         functools.partial(_conv_grad_w_kernel, k=k, stride=stride, ho=ho,
-                          wo=wo, n_b=B),
+                          wo=wo, n_j=n_j, n_b=B),
         grid=(n_j, B),
         in_specs=[
             pl.BlockSpec((1, Hp, Wp, C), lambda j, b: (b, 0, 0, 0)),
             pl.BlockSpec((1, ho, wo, bn_), lambda j, b: (b, 0, 0, j)),
             pl.BlockSpec((1, Hp, Wp, C), lambda j, b: (b, 0, 0, 0)),
             pl.BlockSpec((1, ho, wo, bn_), lambda j, b: (b, 0, 0, j)),
-            pl.BlockSpec((1, 1), lambda j, b: (0, 0)),      # tau scalar
+            pl.BlockSpec(memory_space=pltpu.SMEM),          # tau scalar
         ],
         out_specs=[
             pl.BlockSpec((k * k * C, bn_), lambda j, b: (0, j)),
-            pl.BlockSpec((k * k, 1), lambda j, b: (0, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),          # tile flags
         ],
         out_shape=[
             jax.ShapeDtypeStruct((k * k * C, doutp), jnp.int8),
-            jax.ShapeDtypeStruct((k * k, n_j), jnp.int32),
+            jax.ShapeDtypeStruct((k * k * n_j,), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((k * k * C, bn_), jnp.float32),
             pltpu.VMEM((k * k * C, bn_), jnp.float32),
         ],
         interpret=interpret,
-    )(xm, gmp, xq, gqp, tau.reshape(1, 1).astype(jnp.float32))
+    )(xm, gmp, xq, gqp, tau.reshape(1).astype(jnp.float32))
     sign = to_patch_major(out[:, :dout], k, C)
-    return sign, stats
+    return sign, stats.reshape(k * k, n_j)

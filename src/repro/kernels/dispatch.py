@@ -129,15 +129,26 @@ def psg_grad_w(x2: jnp.ndarray, gy2: jnp.ndarray, cfg: PSGConfig
     level on the reference backend (fallback ratio = fraction of entries
     below the confidence threshold).  Both are in [0, 1] and feed the same
     energy model (``core/energy.py``).
+
+    Traced under a data-parallel mesh (``distributed.sharding
+    .activation_sharding``), the kernel runs per shard and the partial
+    products are summed (``ops.psg_grad_w_sharded``): the compiler cannot
+    partition a Mosaic kernel by itself.
     """
+    from repro.distributed.sharding import ctx_data_axes
+
     backend = resolve_backend(cfg)
     xf = x2.astype(jnp.float32)
     gf = gy2.astype(jnp.float32)
     if backend == BACKEND_REFERENCE:
         return (ref.psg_grad_w_ref(xf, gf, cfg),
                 ref.psg_fallback_ratio_ref(xf, gf, cfg))
-    return ops.psg_grad_w(xf, gf, cfg,
-                          interpret=backend != BACKEND_MOSAIC)
+    interpret = backend != BACKEND_MOSAIC
+    mesh, axes = ctx_data_axes(x2.shape[0])
+    if axes:
+        return ops.psg_grad_w_sharded(xf, gf, cfg, mesh, axes,
+                                      interpret=interpret)
+    return ops.psg_grad_w(xf, gf, cfg, interpret=interpret)
 
 
 def quantize(x: jnp.ndarray, bits: int,

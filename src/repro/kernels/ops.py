@@ -19,6 +19,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.config import PSGConfig
 from repro.core.quant import qscale
@@ -64,6 +65,42 @@ def psg_grad_w(x2: jnp.ndarray, gy2: jnp.ndarray, cfg: PSGConfig,
         xm_c, gm_c, xq_c, gq_c, tau_codes, interpret=interpret)
     fallback_ratio = jnp.mean(stats.astype(jnp.float32))
     return sign_i8.astype(jnp.float32), fallback_ratio
+
+
+@partial(jax.jit, static_argnames=("cfg", "mesh", "axes", "interpret"))
+def psg_grad_w_sharded(x2: jnp.ndarray, gy2: jnp.ndarray, cfg: PSGConfig,
+                       mesh, axes: Tuple[str, ...], interpret: bool = True
+                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`psg_grad_w` with the rows of ``x2``/``gy2`` split over the
+    mesh ``axes`` (data parallelism).
+
+    A Pallas kernel cannot be partitioned by the compiler, so each shard
+    runs the tiled code product on its own rows inside ``shard_map`` and
+    the partial products are summed across shards.  The quantization
+    scales, the threshold and the Eq. (2) select see the global batch, as
+    on one device; only the f32 summation order differs.  The fallback
+    ratio counts the same (bm, bn) output tiles as the fused kernel,
+    padding included.
+    """
+    codes = [_codes(x2, cfg.bits_x_msb)[0], _codes(gy2, cfg.bits_g_msb)[0],
+             _codes(x2, cfg.bits_x)[0], _codes(gy2, cfg.bits_g)[0]]
+
+    def local(xm, gm, xq, gq):
+        prod = partial(_pm.predictor_matmul_pallas, interpret=interpret)
+        return (jax.lax.psum(prod(xm, gm), axes),
+                jax.lax.psum(prod(xq, gq), axes))
+
+    g_msb, g_full = jax.shard_map(
+        local, mesh=mesh, in_specs=(P(axes, None),) * 4,
+        out_specs=(P(), P()), check_vma=False)(*codes)
+    din, dout = g_msb.shape
+    bm, bn = min(_pm.DEFAULT_BM, din), min(_pm.DEFAULT_BN, dout)
+    tau = cfg.beta * jnp.max(jnp.abs(g_msb))
+    conf = jnp.abs(_pm.pad_to(g_msb, bm, bn)) >= tau
+    n_i, n_j = conf.shape[0] // bm, conf.shape[1] // bn
+    tiles = jnp.all(conf.reshape(n_i, bm, n_j, bn), axis=(1, 3))
+    sign = jnp.where(conf[:din, :dout], jnp.sign(g_msb), jnp.sign(g_full))
+    return sign, jnp.mean(jnp.logical_not(tiles).astype(jnp.float32))
 
 
 @partial(jax.jit, static_argnames=("bits", "interpret"))

@@ -72,6 +72,8 @@ def main(argv=None):
     import jax
     import jax.numpy as jnp
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.distributed:
         if args.coordinator is not None:
             jax.distributed.initialize(args.coordinator,

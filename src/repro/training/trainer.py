@@ -231,7 +231,7 @@ class Trainer:
     def _dispatch(self, chunk, in_flight, log_every):
         """device_put + launch one chunk; sync the previous one after."""
         steps, batches, incs = chunk
-        batches, incs = self._place(batches, incs)
+        batches, incs = self.place(batches, incs)
         with self._mesh_ctx():
             t0 = time.perf_counter()
             self.state, stacked = self._chunk_fn(self.state, batches, incs)
@@ -246,7 +246,10 @@ class Trainer:
             self._save(steps[-1])
         return steps, t0, stacked
 
-    def _place(self, batches, incs):
+    def place(self, batches, incs):
+        """Put one stacked chunk where the chunk program reads it: the
+        default device, or under a mesh the batch axis (axis 1) split over
+        the data axes and the increments replicated."""
         incs = jnp.asarray(incs)
         if self.mesh is None:
             return batches, incs

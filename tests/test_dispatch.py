@@ -177,3 +177,44 @@ def test_energy_uses_measured_fallback():
     f_lo = energy.measured_psg_factor(e2, 0.1)
     f_hi = energy.measured_psg_factor(e2, 0.9)
     assert f_lo < f_hi < 1.0
+
+
+def test_data_parallel_psg_grad_w_matches_one_device():
+    """Under a data-parallel mesh the kernel runs per shard inside
+    shard_map (a Mosaic kernel cannot be partitioned by the compiler) and
+    the partial code products are summed: same signs and the same tile
+    fallback ratio as the one-device kernel.  Subprocess: the suite keeps
+    the default single-device runtime."""
+    import os
+    import subprocess
+    import sys
+    script = r"""
+import jax, jax.numpy as jnp, numpy as np
+assert jax.device_count() == 4
+from repro.core.config import PSGConfig
+from repro.distributed.sharding import activation_sharding
+from repro.kernels import dispatch, ops
+from repro.launch.mesh import make_mesh
+cfg = PSGConfig(enabled=True)
+rng = np.random.default_rng(0)
+for n, din, dout in ((256, 144, 16), (64, 200, 130)):
+    x = jnp.asarray(np.maximum(rng.standard_normal((n, din)), 0), jnp.float32)
+    g = jnp.asarray(rng.standard_normal((n, dout)), jnp.float32)
+    want, want_r = ops.psg_grad_w(x, g, cfg)
+    mesh = make_mesh((4, 1), ("data", "model"))
+    with activation_sharding(mesh):
+        got, got_r = jax.jit(lambda a, b: dispatch.psg_grad_w(a, b, cfg))(x, g)
+        jaxpr = jax.make_jaxpr(lambda a, b: dispatch.psg_grad_w(a, b, cfg))(x, g)
+    assert "shard_map" in str(jaxpr)
+    assert np.array_equal(np.asarray(got), np.asarray(want)), (n, din, dout)
+    assert float(got_r) == float(want_r), (float(got_r), float(want_r))
+print("SHARDED_OK")
+"""
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               XLA_FLAGS=os.environ.get("XLA_FLAGS", "")
+               + " --xla_force_host_platform_device_count=4")
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "SHARDED_OK" in out.stdout

@@ -262,3 +262,19 @@ def test_jax_distributed_two_process_world():
         a = np.load(os.path.join(d0, f"step_{steps - 1:08d}.npz"))
         b = np.load(os.path.join(d1, f"step_{steps - 1:08d}.npz"))
         assert any(not np.array_equal(a[k], b[k]) for k in a.files)
+
+
+def test_bench_ft_parent_stays_off_the_device():
+    """bench_ft's in-process scenarios must not initialize a JAX backend:
+    on a chip machine the kill-and-restart children it starts next could
+    not get the chip from a parent that holds it."""
+    code = ("import sys; sys.path.insert(0, '.')\n"
+            "from benchmarks import bench_ft\n"
+            "rows = bench_ft._host_scenarios()\n"
+            "assert all(r['recovered'] for r in rows), rows\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    subprocess.run([sys.executable, "-c", code], cwd=root, env=env,
+                   check=True, timeout=120)

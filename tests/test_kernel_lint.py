@@ -135,6 +135,30 @@ def test_well_tiled_copy_is_clean():
     assert lint_kernel(fn, *args, name="good") == []
 
 
+def _flag_kernel(x_ref, o_ref):
+    o_ref[...] = x_ref[...]
+
+
+@pytest.mark.parametrize("flags, clean", [
+    # one (1, 1) VMEM block per grid cell of a (2, 2) array: Mosaic refuses
+    # it ("divisible by 8 and 128 ... or equal to ... the overall array"),
+    # however small the block is
+    (pl.BlockSpec((1, 1), lambda i, j: (i, j)), False),
+    # the same flags as one whole-array SMEM operand compile
+    (pl.BlockSpec(memory_space=pltpu.SMEM), True),
+])
+def test_subtile_vmem_block_is_caught_smem_is_not(flags, clean):
+    def fn(a):
+        return pl.pallas_call(
+            _flag_kernel, grid=(2, 2), in_specs=[flags], out_specs=flags,
+            out_shape=jax.ShapeDtypeStruct((2, 2), jnp.int32),
+            interpret=True)(a)
+
+    rules = {f.rule for f in lint_kernel(
+        fn, jax.ShapeDtypeStruct((2, 2), jnp.int32), name="flags")}
+    assert ("tile-alignment" not in rules) == clean, rules
+
+
 def test_vmem_budget_overflow_is_caught():
     big = jax.ShapeDtypeStruct((4096, 4096), jnp.float32)
 

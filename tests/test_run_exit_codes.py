@@ -82,3 +82,17 @@ def test_json_still_written_before_nonzero_exit(tmp_path, monkeypatch):
         run.main(["--json-audit", path])
     with open(path) as f:
         assert json.load(f)["lint_errors"] == ["kernel_lint"]
+
+
+def test_raising_bench_exits_nonzero(monkeypatch, capsys):
+    # a bench that raised still gets its ERROR row, but the run fails
+    import benchmarks.bench_kernels as bench_kernels
+
+    def boom(fast=True):
+        raise RuntimeError("kernel refused")
+    monkeypatch.setattr(bench_kernels, "run", boom)
+    with pytest.raises(SystemExit) as e:
+        run.main(["--only", "kernels"])
+    assert e.value.code == 1
+    assert "kernels,0.0,ERROR:RuntimeError:kernel refused" in \
+        capsys.readouterr().out
