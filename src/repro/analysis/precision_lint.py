@@ -47,7 +47,7 @@ from repro.analysis.dataflow import (DataflowResult, ReductionSite, analyze,
 # site-substring pattern -> justification.  Empty on main: every shipped
 # surface accumulates in f32.  (Example shape, should a narrow accumulator
 # ever be intentional:
-#   "psg_grad_w_pallas/pallas": "int8 sign votes are saturating counters,"
+#   "conv_grad_w_pallas/pallas": "int8 sign votes are saturating counters,"
 #                               " not partial sums — Eq. (2) needs signs")
 ALLOWLIST: Dict[str, str] = {}
 

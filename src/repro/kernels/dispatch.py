@@ -132,8 +132,8 @@ def psg_grad_w(x2: jnp.ndarray, gy2: jnp.ndarray, cfg: PSGConfig
 
     Traced under a data-parallel mesh (``distributed.sharding
     .activation_sharding``), the kernel runs per shard and the partial
-    products are summed (``ops.psg_grad_w_sharded``): the compiler cannot
-    partition a Mosaic kernel by itself.
+    products are summed (``ops.psg_grad_w`` with mesh axes): the compiler
+    cannot partition a Mosaic kernel by itself.
     """
     from repro.distributed.sharding import ctx_data_axes
 
@@ -143,12 +143,9 @@ def psg_grad_w(x2: jnp.ndarray, gy2: jnp.ndarray, cfg: PSGConfig
     if backend == BACKEND_REFERENCE:
         return (ref.psg_grad_w_ref(xf, gf, cfg),
                 ref.psg_fallback_ratio_ref(xf, gf, cfg))
-    interpret = backend != BACKEND_MOSAIC
     mesh, axes = ctx_data_axes(x2.shape[0])
-    if axes:
-        return ops.psg_grad_w_sharded(xf, gf, cfg, mesh, axes,
-                                      interpret=interpret)
-    return ops.psg_grad_w(xf, gf, cfg, interpret=interpret)
+    return ops.psg_grad_w(xf, gf, cfg, interpret=backend != BACKEND_MOSAIC,
+                          mesh=mesh if axes else None, axes=axes)
 
 
 def quantize(x: jnp.ndarray, bits: int,
@@ -311,7 +308,6 @@ def shipped_kernels() -> Dict[str, Tuple[Callable, tuple]]:
     S = jax.ShapeDtypeStruct
     # PSG matmul operands: N=1024 tokens, din=256 -> dout=256 (grid 2x2x2)
     xm, gm = S((1024, 256), i8), S((1024, 256), i8)
-    xq, gq = S((1024, 256), i8), S((1024, 256), i16)
     tau = S((), f32)
     # attention operands: S=256 (2 q-blocks, 2 kv-blocks), GQA 4->2 heads.
     # Registered at BOTH fp32 and the model's real bf16 activation dtype —
@@ -327,14 +323,15 @@ def shipped_kernels() -> Dict[str, Tuple[Callable, tuple]]:
     scales6 = S((6,), f32)
     lims = (127.0, 7.0, 32767.0, 511.0)     # default PSGConfig code limits
     entries: Dict[str, Tuple[Callable, tuple]] = {
-        "psg_grad_w_pallas": (
-            lambda a, b, c, d, t: psg_matmul.psg_grad_w_pallas(
-                a, b, c, d, t, interpret=True),
-            (xm, gm, xq, gq, tau)),
         "predictor_matmul_pallas": (
             lambda a, b: psg_matmul.predictor_matmul_pallas(
                 a, b, interpret=True),
             (xm, gm)),
+        # the same kernel on the full 8-bit x 16-bit codes
+        "predictor_matmul_pallas[full]": (
+            lambda a, b: psg_matmul.predictor_matmul_pallas(
+                a, b, interpret=True),
+            (S((1024, 256), i8), S((1024, 256), i16))),
         "quantize_pallas": (
             functools.partial(quant.quantize_pallas, bits=8, interpret=True),
             (S((512, 1024), f32,),)),
@@ -408,7 +405,6 @@ def kernel_acc_dtypes() -> Dict[str, str]:
     bandwidth story, never an accumulation story (the PR 7 lesson).
     """
     return {
-        "psg_grad_w_pallas": "float32",
         "predictor_matmul_pallas": "float32",
         "quantize_pallas": "float32",
         "flash_attention": "float32",

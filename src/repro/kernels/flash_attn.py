@@ -19,7 +19,7 @@ kernels keep every (S, T)-shaped quantity in VMEM tiles:
   transposed (Q innermost); each kv-block tile carries FOUR fp32 VMEM
   accumulators — the MSB *predictor* products and the full-precision-grid
   code products of the ``dv = P^T dO`` and ``dk = dS^T q`` contractions —
-  exactly the dual-accumulator structure of ``psg_matmul._psg_kernel``.
+  the same two products ``ops.psg_grad_w`` takes from ``psg_matmul``.
   Operands are quantized **in-tile** onto per-tensor grids whose scalar
   scales come in as kernel operands (probabilities live on the fixed
   [0, 1] grid, so their codes need no data-dependent scale), which keeps

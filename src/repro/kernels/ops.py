@@ -38,69 +38,43 @@ def _codes(x: jnp.ndarray, bits: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return q.astype(dt), s
 
 
-@partial(jax.jit, static_argnames=("cfg", "interpret"))
+@partial(jax.jit, static_argnames=("cfg", "interpret", "mesh", "axes"))
 def psg_grad_w(x2: jnp.ndarray, gy2: jnp.ndarray, cfg: PSGConfig,
-               interpret: bool = True
+               interpret: bool = True, mesh=None, axes: Tuple[str, ...] = ()
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Tile-level PSG weight gradient.
 
-    Returns (sign (din,dout) float32 in {-1,0,+1}, fallback_tile_ratio
-    scalar — the fraction of output tiles that needed the full product; the
-    energy model charges full-precision MACs only for those).
+    Two code products in the Pallas kernel (MSB predictor codes, full
+    codes), then the Eq. (2) select (``psg_matmul.psg_select``).  Returns
+    (sign (din,dout) float32 in {-1,0,+1}, fallback_tile_ratio scalar — the
+    fraction of output tiles that needed the full product; the energy model
+    charges full-precision MACs only for those).
+
+    With mesh ``axes`` (data parallelism) the rows of ``x2``/``gy2`` are
+    split over them: a Pallas kernel cannot be partitioned by the compiler,
+    so each shard runs the products on its own rows inside ``shard_map``
+    and the partial products are summed across shards.  The quantization
+    scales, the threshold and the select see the global batch, as on one
+    device; only the f32 summation order differs.
     """
-    xm_c, xm_s = _codes(x2, cfg.bits_x_msb)
-    gm_c, gm_s = _codes(gy2, cfg.bits_g_msb)
-    xq_c, xq_s = _codes(x2, cfg.bits_x)
-    gq_c, gq_s = _codes(gy2, cfg.bits_g)
-    # threshold in *code units* of the predictor product:
-    # tau_real = beta * max|g_msb_real|; g_msb_real = codes * (xm_s * gm_s)
-    # -> tau_codes = beta * max|codes-product|
-    # we need max|g_msb| first: cheap jnp matmul on the narrow codes would
-    # defeat the kernel, so compute it from the kernel's own pass-1 product.
-    g_msb_codes = _pm.predictor_matmul_pallas(xm_c, gm_c, interpret=interpret)
-    tau_codes = cfg.beta * jnp.max(jnp.abs(g_msb_codes))
-    # rescale full-product codes so both accumulators share tau units:
-    # sign(g_full) is scale-invariant, so no rescale needed for the sign.
-    sign_i8, stats = _pm.psg_grad_w_pallas(
-        xm_c, gm_c, xq_c, gq_c, tau_codes, interpret=interpret)
-    fallback_ratio = jnp.mean(stats.astype(jnp.float32))
-    return sign_i8.astype(jnp.float32), fallback_ratio
+    codes = (_codes(x2, cfg.bits_x_msb)[0], _codes(gy2, cfg.bits_g_msb)[0],
+             _codes(x2, cfg.bits_x)[0], _codes(gy2, cfg.bits_g)[0])
+    prod = partial(_pm.predictor_matmul_pallas, interpret=interpret)
 
+    def products(xm, gm, xq, gq):
+        return prod(xm, gm), prod(xq, gq)
 
-@partial(jax.jit, static_argnames=("cfg", "mesh", "axes", "interpret"))
-def psg_grad_w_sharded(x2: jnp.ndarray, gy2: jnp.ndarray, cfg: PSGConfig,
-                       mesh, axes: Tuple[str, ...], interpret: bool = True
-                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """:func:`psg_grad_w` with the rows of ``x2``/``gy2`` split over the
-    mesh ``axes`` (data parallelism).
+    def summed(*c):
+        return tuple(jax.lax.psum(p, axes) for p in products(*c))
 
-    A Pallas kernel cannot be partitioned by the compiler, so each shard
-    runs the tiled code product on its own rows inside ``shard_map`` and
-    the partial products are summed across shards.  The quantization
-    scales, the threshold and the Eq. (2) select see the global batch, as
-    on one device; only the f32 summation order differs.  The fallback
-    ratio counts the same (bm, bn) output tiles as the fused kernel,
-    padding included.
-    """
-    codes = [_codes(x2, cfg.bits_x_msb)[0], _codes(gy2, cfg.bits_g_msb)[0],
-             _codes(x2, cfg.bits_x)[0], _codes(gy2, cfg.bits_g)[0]]
-
-    def local(xm, gm, xq, gq):
-        prod = partial(_pm.predictor_matmul_pallas, interpret=interpret)
-        return (jax.lax.psum(prod(xm, gm), axes),
-                jax.lax.psum(prod(xq, gq), axes))
-
-    g_msb, g_full = jax.shard_map(
-        local, mesh=mesh, in_specs=(P(axes, None),) * 4,
-        out_specs=(P(), P()), check_vma=False)(*codes)
-    din, dout = g_msb.shape
-    bm, bn = min(_pm.DEFAULT_BM, din), min(_pm.DEFAULT_BN, dout)
-    tau = cfg.beta * jnp.max(jnp.abs(g_msb))
-    conf = jnp.abs(_pm.pad_to(g_msb, bm, bn)) >= tau
-    n_i, n_j = conf.shape[0] // bm, conf.shape[1] // bn
-    tiles = jnp.all(conf.reshape(n_i, bm, n_j, bn), axis=(1, 3))
-    sign = jnp.where(conf[:din, :dout], jnp.sign(g_msb), jnp.sign(g_full))
-    return sign, jnp.mean(jnp.logical_not(tiles).astype(jnp.float32))
+    if axes:
+        prods = jax.shard_map(summed, mesh=mesh,
+                              in_specs=(P(axes, None),) * 4,
+                              out_specs=(P(), P()), check_vma=False)(*codes)
+    else:
+        prods = products(*codes)
+    sign, tiles = _pm.psg_select(*prods, cfg.beta)
+    return sign, jnp.mean(tiles.astype(jnp.float32))
 
 
 @partial(jax.jit, static_argnames=("bits", "interpret"))

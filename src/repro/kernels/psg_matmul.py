@@ -1,30 +1,32 @@
-"""Pallas TPU kernel: tile-level Predictive Sign Gradient weight-gradient.
+"""Pallas TPU kernel and select for the Predictive Sign Gradient
+weight-gradient.
 
-This is the kernel the training backward pass actually executes: the
-``custom_vjp`` in ``core/psg.py`` routes every PSG weight gradient here
-through ``kernels/dispatch.py`` (backend selection rules in DESIGN.md
-§Dispatch), and the per-tile fallback stats it emits drive the measured
-energy accounting (``core/energy.py``).
+This is what the training backward pass executes: the ``custom_vjp`` in
+``core/psg.py`` routes every PSG weight gradient through
+``kernels/dispatch.py`` (backend selection rules in DESIGN.md §Dispatch) to
+``kernels/ops.psg_grad_w``, which runs :func:`predictor_matmul_pallas`
+twice and :func:`psg_select` once; the per-tile fallback flags of the
+select drive the measured energy accounting (``core/energy.py``).
 
 Computes ``sign_psg(x^T g_y)`` for a weight matmul's backward pass with the
 paper's Eq. (2) semantics, adapted to the TPU memory/compute hierarchy
 (DESIGN.md §3.2):
 
 * the MSB *predictor* product runs over narrow operands (4-bit / 10-bit
-  codes carried in int8/int16 containers) — on real TPUs this is the int8
-  MXU path at ~2x bf16 throughput and ~1/10 the per-MAC energy;
-* the *fallback* full product is computed **per output tile**, only when the
-  tile contains at least one entry below the confidence threshold
-  ``tau = beta * max|g_msb|`` — the MXU is dense, so element-level fallback
-  (the paper's bit-serial formulation) is replaced by tile-level
-  ``pl.when`` gating.  Output values are identical to the element-level
-  oracle; only the *energy accounting* is tile-granular.
+  codes carried in int8/int16 containers);
+* the *full* product runs over the 8-bit / 16-bit codes with the same
+  kernel;
+* the select takes the predictor's sign where ``|g_msb| >= tau = beta *
+  max|g_msb|`` and the full product's sign elsewhere.  Output values are
+  identical to the element-level oracle; only the *energy accounting* is
+  tile-granular: a (BM, BN) output tile with any entry below ``tau`` is
+  charged the full product.
 
 Grid/BlockSpec layout: grid = (din/BM, dout/BN, N/BK) with the reduction
 axis innermost; a VMEM scratch accumulator carries partial sums across the
-k-steps; outputs are written on the last k-step.  Tile sizes default to
+k-steps; the output is written on the last k-step.  Tile sizes default to
 (128, 128, 512) — MXU-aligned (multiples of 128) and a VMEM working set of
-BK*(BM+BN)*2B + BM*BN*8B ≈ 0.6 MB, far under the ~16 MB/core budget, which
+BK*(BM+BN)*2B + BM*BN*4B ≈ 0.3 MB, far under the ~16 MB/core budget, which
 leaves room for double-buffered pipelining of the HBM->VMEM streams.
 """
 from __future__ import annotations
@@ -47,50 +49,8 @@ DEFAULT_BK = 512
 CODE_PRECISION = jax.lax.Precision.HIGHEST
 
 
-def _psg_kernel(xm_ref, gm_ref, xq_ref, gq_ref, tau_ref,
-                out_ref, stats_ref, acc_msb, acc_full, *, n_j: int, n_k: int):
-    """One (i, j) output tile; k-loop accumulates in VMEM scratch.
-
-    xm/xq: (BK, BM) MSB / full codes of x;  gm/gq: (BK, BN) of g_y;
-    tau: (1,) f32 in SMEM.  out: (BM, BN) sign in {-1, 0, +1} (int8);
-    stats: (n_i * n_j,) int32 in SMEM — entry ``i * n_j + j`` is 1 if this
-    tile needed the full-product fallback (energy accounting).
-    """
-    i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_msb[...] = jnp.zeros_like(acc_msb)
-        acc_full[...] = jnp.zeros_like(acc_full)
-
-    # predictor product: narrow codes (int8 containers) — int MXU path
-    xm = xm_ref[...].astype(jnp.float32)
-    gm = gm_ref[...].astype(jnp.float32)
-    acc_msb[...] += jnp.dot(xm.T, gm, preferred_element_type=jnp.float32,
-                            precision=CODE_PRECISION)
-
-    # full-precision-grid product (8b x 16b codes) — accumulated every step;
-    # on real hardware this stream is elided for confident tiles via the
-    # two-pass variant (ops.py `two_pass=True`); the fused single-pass
-    # version computes it but only *uses* it on fallback tiles.
-    xq = xq_ref[...].astype(jnp.float32)
-    gq = gq_ref[...].astype(jnp.float32)
-    acc_full[...] += jnp.dot(xq.T, gq, preferred_element_type=jnp.float32,
-                            precision=CODE_PRECISION)
-
-    @pl.when(k == n_k - 1)
-    def _finish():
-        g_msb = acc_msb[...]
-        conf = jnp.abs(g_msb) >= tau_ref[0]
-        need_full = jnp.logical_not(jnp.all(conf))
-        g_full = acc_full[...]
-        sign = jnp.where(conf, jnp.sign(g_msb), jnp.sign(g_full))
-        out_ref[...] = sign.astype(jnp.int8)
-        stats_ref[i * n_j + j] = need_full.astype(jnp.int32)
-
-
 def _pred_kernel(xm_ref, gm_ref, out_ref, acc, *, n_k: int):
-    """Predictor-only matmul (pass 1 of the two-pass variant)."""
+    """One (i, j) tile of a code product; the k-loop accumulates in VMEM."""
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -115,63 +75,15 @@ def pad_to(x: jnp.ndarray, m0: int, m1: int) -> jnp.ndarray:
     return x
 
 
-def psg_grad_w_pallas(x_msb: jnp.ndarray, g_msb: jnp.ndarray,
-                      x_q: jnp.ndarray, g_q: jnp.ndarray,
-                      tau: jnp.ndarray,
-                      *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
-                      bk: int = DEFAULT_BK,
-                      interpret: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Tile-level PSG sign gradient.
-
-    Args: code tensors (N, din) / (N, dout) (int8/int16 containers, values on
-    the quantization grids), ``tau`` scalar fp32 threshold **in code units**
-    (i.e. already divided by the product of scales).
-    Returns: (sign (din, dout) int8, tile_fallback (din/bm, dout/bn) int32).
-    """
-    N, din = x_q.shape
-    dout = g_q.shape[1]
-    bm_, bn_, bk_ = min(bm, din), min(bn, dout), min(bk, N)
-    xm = pad_to(x_msb, bk_, bm_)
-    gm = pad_to(g_msb, bk_, bn_)
-    xq = pad_to(x_q, bk_, bm_)
-    gq = pad_to(g_q, bk_, bn_)
-    Np, dinp = xq.shape
-    doutp = gq.shape[1]
-    n_i, n_j, n_k = dinp // bm_, doutp // bn_, Np // bk_
-
-    grid = (n_i, n_j, n_k)
-    out, stats = pl.pallas_call(
-        functools.partial(_psg_kernel, n_j=n_j, n_k=n_k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bk_, bm_), lambda i, j, k: (k, i)),
-            pl.BlockSpec((bk_, bn_), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bk_, bm_), lambda i, j, k: (k, i)),
-            pl.BlockSpec((bk_, bn_), lambda i, j, k: (k, j)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),           # tau scalar
-        ],
-        out_specs=[
-            pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),           # tile flags
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((dinp, doutp), jnp.int8),
-            jax.ShapeDtypeStruct((n_i * n_j,), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bm_, bn_), jnp.float32),
-            pltpu.VMEM((bm_, bn_), jnp.float32),
-        ],
-        interpret=interpret,
-    )(xm, gm, xq, gq, tau.reshape(1).astype(jnp.float32))
-    return out[:din, :dout], stats.reshape(n_i, n_j)
-
-
 def predictor_matmul_pallas(x_msb: jnp.ndarray, g_msb: jnp.ndarray,
                             *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
                             bk: int = DEFAULT_BK,
                             interpret: bool = True) -> jnp.ndarray:
-    """g_msb = x_msb^T @ g_msb codes product (fp32), tiled."""
+    """``x_msb^T @ g_msb`` over integer codes (fp32), tiled.
+
+    Serves both PSG products: the MSB predictor codes and the full codes
+    (int8/int16 containers).
+    """
     N, din = x_msb.shape
     dout = g_msb.shape[1]
     bm_, bn_, bk_ = min(bm, din), min(bn, dout), min(bk, N)
@@ -193,3 +105,26 @@ def predictor_matmul_pallas(x_msb: jnp.ndarray, g_msb: jnp.ndarray,
         interpret=interpret,
     )(xm, gm)
     return out[:din, :dout]
+
+
+def psg_select(g_msb: jnp.ndarray, g_full: jnp.ndarray, beta: float,
+               *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Eq. (2) select over the two code products (XLA, after the kernels).
+
+    Returns ``(sign (din, dout) float32 in {-1, 0, +1}, tile_fallback
+    (ceil(din/bm'), ceil(dout/bn')) bool)`` with ``bm' = min(bm, din)`` and
+    ``bn' = min(bn, dout)``, the kernel's output tiles.  A tile needs the
+    full product when any of its entries, padding included, lies below
+    ``tau = beta * max|g_msb|``.  Both products are in code units: the sign
+    is scale-invariant, and so is ``tau`` relative to ``g_msb``.
+    """
+    din, dout = g_msb.shape
+    bm_, bn_ = min(bm, din), min(bn, dout)
+    tau = beta * jnp.max(jnp.abs(g_msb))
+    conf = jnp.abs(pad_to(g_msb, bm_, bn_)) >= tau
+    n_i, n_j = conf.shape[0] // bm_, conf.shape[1] // bn_
+    tile_fallback = jnp.logical_not(
+        jnp.all(conf.reshape(n_i, bm_, n_j, bn_), axis=(1, 3)))
+    sign = jnp.where(conf[:din, :dout], jnp.sign(g_msb), jnp.sign(g_full))
+    return sign, tile_fallback

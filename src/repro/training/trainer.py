@@ -157,30 +157,6 @@ class Trainer:
                      log_every: int = 0) -> List[Dict[str, float]]:
         from repro.data.pipeline import DataPipeline
 
-        if self._chunk_fn is None:
-            # donate_chunk_state=False (default): donating the carried
-            # TrainState lets XLA CPU rewrite the scanned body in place,
-            # which changes fusion and breaks the bit-for-bit parity with
-            # the per-step loop that tests/test_loop.py pins (measured:
-            # losses drift in the 4th decimal from the second in-chunk step
-            # onward; DESIGN.md §Loop).  The cost is one extra TrainState
-            # copy per chunk.  Opt in per backend/profile with
-            # Trainer(donate_chunk_state=True) — the curve then matches
-            # the per-step loop to fp tolerance, not bit-for-bit
-            # (tests/test_loop.py::test_donate_chunk_state_parity).
-            donate = (0,) if self.donate_chunk_state else ()
-            # deadline_s > 0 opts into the TIMED chunk program: one ordered
-            # host callback per scanned step records device-side step
-            # boundaries, so the straggler deadline applies per step, not
-            # per chunk mean (DESIGN.md §Fault-tolerance).  The default
-            # program stays callback-free (CHUNK_CONTRACT).  Ordered
-            # effects are single-device only in XLA, so mesh runs keep
-            # the chunk-mean fallback clock.
-            timer = (self._record_step_time
-                     if self.deadline_s and self.mesh is None else None)
-            self._chunk_fn = jax.jit(
-                make_chunk_step(self.exp, step_timer=timer),
-                donate_argnums=donate)
         planner = ChunkPlanner(self.chunk_steps)
         self._last_sync_t = 0.0
         start = int(self.state.step)
@@ -228,13 +204,44 @@ class Trainer:
         self._final_save()
         return self.history
 
+    def chunk_program(self):
+        """The jitted chunk program the chunked loop dispatches, built on
+        first use: ``(state, batches, incs) -> (state, stacked metrics)``
+        on arguments placed by :meth:`place`."""
+        if self._chunk_fn is None:
+            # donate_chunk_state=False (default): donating the carried
+            # TrainState lets XLA CPU rewrite the scanned body in place,
+            # which changes fusion and breaks the bit-for-bit parity with
+            # the per-step loop that tests/test_loop.py pins (measured:
+            # losses drift in the 4th decimal from the second in-chunk step
+            # onward; DESIGN.md §Loop).  The cost is one extra TrainState
+            # copy per chunk.  Opt in per backend/profile with
+            # Trainer(donate_chunk_state=True) — the curve then matches
+            # the per-step loop to fp tolerance, not bit-for-bit
+            # (tests/test_loop.py::test_donate_chunk_state_parity).
+            donate = (0,) if self.donate_chunk_state else ()
+            # deadline_s > 0 opts into the TIMED chunk program: one ordered
+            # host callback per scanned step records device-side step
+            # boundaries, so the straggler deadline applies per step, not
+            # per chunk mean (DESIGN.md §Fault-tolerance).  The default
+            # program stays callback-free (CHUNK_CONTRACT).  Ordered
+            # effects are single-device only in XLA, so mesh runs keep
+            # the chunk-mean fallback clock.
+            timer = (self._record_step_time
+                     if self.deadline_s and self.mesh is None else None)
+            self._chunk_fn = jax.jit(
+                make_chunk_step(self.exp, step_timer=timer),
+                donate_argnums=donate)
+        return self._chunk_fn
+
     def _dispatch(self, chunk, in_flight, log_every):
         """device_put + launch one chunk; sync the previous one after."""
         steps, batches, incs = chunk
         batches, incs = self.place(batches, incs)
         with self._mesh_ctx():
             t0 = time.perf_counter()
-            self.state, stacked = self._chunk_fn(self.state, batches, incs)
+            self.state, stacked = self.chunk_program()(self.state, batches,
+                                                       incs)
         if in_flight is not None:
             self._finalize(in_flight, log_every)
         if self.ckpt_dir and self.ckpt_every and any(

@@ -49,7 +49,7 @@ def test_shipped_kernels_lint_clean():
 def test_registry_enumerates_every_shipped_kernel():
     base = {n.split("[")[0] for n in shipped_kernels()}
     assert base == {
-        "psg_grad_w_pallas", "predictor_matmul_pallas", "conv_fwd_pallas",
+        "predictor_matmul_pallas", "conv_fwd_pallas",
         "conv_grad_w_predictor_pallas", "conv_grad_w_pallas",
         "conv_grad_x_pallas", "quantize_pallas", "flash_attention",
         "flash_bwd_dq_pallas", "flash_bwd_dkv_pallas"}

@@ -71,12 +71,13 @@ def test_psg_grad_w_non_mxu_aligned_tiles(N, din, dout):
 
 @pytest.mark.parametrize("N,din,dout", CIFAR_TILE_SHAPES[:3])
 def test_psg_kernel_stats_grid_matches_executed_tiles(N, din, dout):
-    """The raw kernel's per-tile stats grid covers exactly the padded tile
-    grid — ceil(din/BM) x ceil(dout/BN) with clamped tiles — so the mean
-    is the executed-tile fallback ratio (DESIGN.md §Dispatch caveat)."""
+    """The select's per-tile fallback grid covers exactly the padded tile
+    grid of the kernel's output — ceil(din/BM) x ceil(dout/BN) with clamped
+    tiles — so its mean is the executed-tile fallback ratio that
+    ``ops.psg_grad_w`` reports (DESIGN.md §Dispatch caveat)."""
     from repro.core.quant import quantize_int
     from repro.kernels.psg_matmul import (DEFAULT_BM, DEFAULT_BN,
-                                          psg_grad_w_pallas)
+                                          predictor_matmul_pallas, psg_select)
     cfg = PSGConfig(enabled=True)
     k1, k2 = jax.random.split(jax.random.PRNGKey(3))
     x = jax.random.normal(k1, (N, din))
@@ -85,13 +86,14 @@ def test_psg_kernel_stats_grid_matches_executed_tiles(N, din, dout):
     gm, _ = quantize_int(gy, cfg.bits_g_msb)
     xq, _ = quantize_int(x, cfg.bits_x)
     gq, _ = quantize_int(gy, cfg.bits_g)
-    tau = cfg.beta * jnp.max(jnp.abs(
-        xm.astype(jnp.float32).T @ gm.astype(jnp.float32)))
-    out, stats = psg_grad_w_pallas(xm, gm, xq, gq, tau)
+    out, stats = psg_select(predictor_matmul_pallas(xm, gm),
+                            predictor_matmul_pallas(xq, gq), cfg.beta)
     bm = min(DEFAULT_BM, din)
     bn = min(DEFAULT_BN, dout)
     assert stats.shape == (-(-din // bm), -(-dout // bn))
     assert out.shape == (din, dout)
+    _, ratio = ops.psg_grad_w(x, gy, cfg)
+    assert float(ratio) == float(np.mean(np.asarray(stats)))
 
 
 @pytest.mark.parametrize("beta", [0.02, 0.05, 0.1, 0.3])
@@ -138,7 +140,7 @@ def test_predictor_matmul_pallas_matches_oracle():
 
 def test_psg_kernel_block_shape_sweep():
     """BlockSpec tiling must not change results."""
-    from repro.kernels.psg_matmul import psg_grad_w_pallas
+    from repro.kernels.psg_matmul import predictor_matmul_pallas, psg_select
     from repro.core.psg import quantize_int
     cfg = PSGConfig(enabled=True)
     k1, k2 = jax.random.split(jax.random.PRNGKey(5))
@@ -148,11 +150,11 @@ def test_psg_kernel_block_shape_sweep():
     gm, _ = quantize_int(gy, cfg.bits_g_msb)
     xq, _ = quantize_int(x, cfg.bits_x)
     gq, _ = quantize_int(gy, cfg.bits_g)
-    g_msb = xm.astype(jnp.float32).T @ gm.astype(jnp.float32)
-    tau = cfg.beta * jnp.max(jnp.abs(g_msb))
     outs = []
     for bm, bn, bk in [(32, 32, 64), (64, 64, 128), (128, 64, 256)]:
-        out, _ = psg_grad_w_pallas(xm, gm, xq, gq, tau, bm=bm, bn=bn, bk=bk)
+        prods = [predictor_matmul_pallas(a, b, bm=bm, bn=bn, bk=bk)
+                 for a, b in ((xm, gm), (xq, gq))]
+        out, _ = psg_select(*prods, cfg.beta, bm=bm, bn=bn)
         outs.append(np.asarray(out))
     for o in outs[1:]:
         np.testing.assert_array_equal(outs[0], o)
