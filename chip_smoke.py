@@ -21,8 +21,9 @@ One process drives the chip.  With no option it runs three phases:
 ``--four-chips`` runs only the data-parallel phase: ResNet-74 at global
 batch 128 on a (4, 1) ("data", "model") mesh against one device, at f32
 matmul precision.  With E2-Train off the two trainers step in lockstep
-from the same state, and each step's loss, gradient norm and update must
-agree to fp tolerance.  With E2-Train on they run free: SMD counters
+from the same state: each step's loss must agree to fp tolerance, and its
+parameter update to within a few times what reversing the batch's rows
+changes it on one chip.  With E2-Train on they run free: SMD counters
 identical, losses finite; and the sharded PSG weight gradient at the
 agreement shape matches the host-CPU oracle except where a sign is decided
 within f32 summation error.
@@ -56,6 +57,9 @@ AGREE_SHAPE = (131072, 144, 16)
 # one step of a data-parallel run against one device from the same state,
 # at f32 matmul precision: only the f32 summation order differs
 LOCKSTEP_RTOL = 1e-4
+# the 4-chip update may differ from the 1-chip one by this many times what
+# reversing the batch's rows on one chip changes it (lockstep)
+ORDER_FACTOR = 4.0
 # an f32 sum of N terms drifts by about sqrt(N) * 2^-24 * ||terms||_2 with
 # the summation order; a PSG sign closer than this many drifts to a
 # decision boundary may flip between two orders
@@ -109,11 +113,17 @@ def nominal_steps(exp, executed: int) -> int:
     return n
 
 
-def batch_maker():
+def batch_maker(reverse: bool = False):
+    """Seeded CIFAR-shaped batches; ``reverse`` serves each batch's rows in
+    reverse order: the same examples, so the same step summed in another
+    order."""
     from repro.data.synthetic import GaussianImageTask, make_image_batch
     task = GaussianImageTask(num_classes=10, snr=2.0)
-    return lambda step, shard: make_image_batch(task, SEED, step, shard,
-                                                BATCH)
+
+    def make(step, shard):
+        b = make_image_batch(task, SEED, step, shard, BATCH)
+        return {k: v[::-1] for k, v in b.items()} if reverse else b
+    return make
 
 
 def chunk_seconds(hist) -> list:
@@ -130,12 +140,13 @@ def check_losses(hist, label: str) -> list:
     return losses
 
 
-def make_trainer(exp, chunk_steps: int = CHUNK, mesh=None):
+def make_trainer(exp, chunk_steps: int = CHUNK, mesh=None,
+                 reverse: bool = False):
     import jax
     from repro.training.train_step import init_train_state
     from repro.training.trainer import Trainer
     state = init_train_state(jax.random.PRNGKey(SEED), exp)
-    return Trainer(exp, state, batch_maker(), chunk_steps=chunk_steps,
+    return Trainer(exp, state, batch_maker(reverse), chunk_steps=chunk_steps,
                    deadline_s=0.0, mesh=mesh)
 
 
@@ -311,10 +322,13 @@ def rel_gap(a, b) -> float:
 
 
 def lockstep(exp, mesh, executed: int, label: str):
-    """Step a 1-chip and a 4-chip trainer one step at a time, the 4-chip
-    one restarted from the 1-chip state before every step; return the
-    4-chip trainer.  Each step's loss and parameter update must agree to
-    LOCKSTEP_RTOL."""
+    """Step three trainers one step at a time from the same state: one
+    chip, one chip fed each batch in reverse row order (the control), and
+    four chips; return the 4-chip trainer.  Each step's loss must agree to
+    LOCKSTEP_RTOL.  The parameter update is a sum over the batch that
+    largely cancels before BatchNorm, so its f32 summation error is far
+    above the loss's: the 4-chip update must lie within ORDER_FACTOR times
+    the control's distance from the 1-chip update."""
     import jax
     from repro.distributed.sharding import state_shardings
 
@@ -322,33 +336,42 @@ def lockstep(exp, mesh, executed: int, label: str):
         return np.concatenate([np.ravel(v) for v in jax.tree.leaves(tree)])
 
     one = make_trainer(exp, chunk_steps=1)
+    rev = make_trainer(exp, chunk_steps=1, reverse=True)
     four = make_trainer(exp, chunk_steps=1, mesh=mesh)
     gaps = []
     while one.executed_steps < executed:
         before = jax.device_get(one.state)
+        rev.state = jax.device_put(before)
         four.state = jax.device_put(before, state_shardings(before, mesh))
-        one.run(1)
-        four.run(1)
-        check((one.executed_steps, one.dropped_steps)
-              == (four.executed_steps, four.dropped_steps),
+        for tr in (one, rev, four):
+            tr.run(1)
+        check(len({(tr.executed_steps, tr.dropped_steps)
+                   for tr in (one, rev, four)}) == 1,
               f"{label}: SMD counters differ")
         if len(one.history) == len(gaps):
             continue                            # an SMD-dropped step
-        h1, h4 = one.history[-1], four.history[-1]
         p0 = flat(before.params)
-        u1 = flat(jax.device_get(one.state.params)) - p0
-        u4 = flat(jax.device_get(four.state.params)) - p0
-        gaps.append((rel_gap(h1["total_loss"], h4["total_loss"]),
-                     float(np.linalg.norm(u4 - u1) / np.linalg.norm(u1))))
-    check_losses(one.history, f"{label} 1 chip")
-    check_losses(four.history, f"{label} 4 chips")
+        u1, ur, u4 = (flat(jax.device_get(tr.state.params)) - p0
+                      for tr in (one, rev, four))
+        gaps.append((rel_gap(one.history[-1]["total_loss"],
+                             four.history[-1]["total_loss"]),
+                     float(np.linalg.norm(u4 - u1) / np.linalg.norm(u1)),
+                     float(np.linalg.norm(ur - u1) / np.linalg.norm(u1))))
+    for tr, where in ((one, "1 chip"), (rev, "1 chip reversed"),
+                      (four, "4 chips")):
+        check_losses(tr.history, f"{label} {where}")
     print(f"{label}: losses 1 chip "
           f"{[round(h['total_loss'], 5) for h in one.history]}", flush=True)
-    for name, col in (("loss", 0), ("update", 1)):
-        print(f"{label}: lockstep relative {name} gap 4 chips vs 1 per step "
+    for name, col in (("loss gap 4 chips vs 1", 0),
+                      ("update gap 4 chips vs 1", 1),
+                      ("update gap reversed rows vs 1 chip", 2)):
+        print(f"{label}: lockstep relative {name} per step "
               f"{[float(f'{g[col]:.3e}') for g in gaps]}", flush=True)
-    check(all(g <= LOCKSTEP_RTOL for step in gaps for g in step),
-          f"{label}: a lockstep step differs by more than {LOCKSTEP_RTOL}")
+    check(all(loss <= LOCKSTEP_RTOL for loss, _, _ in gaps),
+          f"{label}: a lockstep loss differs by more than {LOCKSTEP_RTOL}")
+    check(all(u4 <= ORDER_FACTOR * ur for _, u4, ur in gaps),
+          f"{label}: a 4-chip update differs by more than {ORDER_FACTOR}x "
+          f"the reversed-row control")
     return four
 
 
@@ -357,7 +380,9 @@ def four_chip_phase() -> None:
 
     E2-Train off steps in lockstep at f32 matmul precision, so a gap
     measures the sharding of one step and not the bf16 rounding of two
-    differently compiled programs.  Two free runs are not compared: from a
+    differently compiled programs; the reversed-row control measures what
+    the f32 summation order alone does to that step, on the same chip
+    kind and at the same precision.  Two free runs are not compared: from a
     random init the loss of ResNet-74 spikes over the first steps, and
     that amplifies f32 summation-order differences about tenfold per step
     (PERF.md).  The E2-Train-off run uses the E2-Train run's settings
