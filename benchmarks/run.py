@@ -8,11 +8,6 @@ through ``Trainer.energy_report()``, plus the config-derived Table 3 sweep
 for ResNet-74 — every field straight from :class:`EnergyReport`, so CI can
 diff the numbers PR over PR.
 
-``--json-throughput [PATH]`` (default ``BENCH_throughput.json``) records
-the loop-throughput trajectory: executed steps/s of the per-step vs
-chunked loop and the chunk speedup on the depth-14 ResNet CPU configs
-(benchmarks/bench_throughput.py).
-
 ``--json-conv [PATH]`` (default ``BENCH_conv.json``) records the
 fused-conv trajectory: implicit-GEMM vs materialized-im2col activation
 bytes moved per training step on the paper-shaped ResNet-74 config plus
@@ -103,17 +98,11 @@ def main(argv=None) -> None:
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None,
                     help="comma-separated bench names (smd,slu,psg,e2train,"
-                         "cnn,convergence,kernels,conv,attn,throughput,"
+                         "cnn,convergence,kernels,conv,attn,"
                          "roofline,audit,ft)")
     ap.add_argument("--json", nargs="?", const="BENCH_energy.json",
                     default=None, metavar="PATH",
                     help="write the EnergyReport trajectory record to PATH "
-                         "and exit (skips the CSV benches)")
-    ap.add_argument("--json-throughput", nargs="?",
-                    const="BENCH_throughput.json", default=None,
-                    metavar="PATH",
-                    help="write the chunked-loop throughput record "
-                         "(steps/s per-step vs chunked + speedup) to PATH "
                          "and exit (skips the CSV benches)")
     ap.add_argument("--json-conv", nargs="?", const="BENCH_conv.json",
                     default=None, metavar="PATH",
@@ -140,18 +129,13 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     fast = not args.full
 
-    if args.json or args.json_throughput or args.json_conv \
+    if args.json or args.json_conv \
             or args.json_attn or args.json_audit \
             or args.json_ft:                                 # write all given
         if args.json:
             with open(args.json, "w") as f:
                 json.dump(energy_json(fast=fast), f, indent=2)
             print(f"wrote {args.json}", file=sys.stderr)
-        if args.json_throughput:
-            from benchmarks.bench_throughput import throughput_json
-            with open(args.json_throughput, "w") as f:
-                json.dump(throughput_json(fast=fast), f, indent=2)
-            print(f"wrote {args.json_throughput}", file=sys.stderr)
         if args.json_conv:
             from benchmarks.bench_conv import (IncompleteAccountingError,
                                                conv_json)
@@ -208,7 +192,7 @@ def main(argv=None) -> None:
     from benchmarks import (bench_attn, bench_audit, bench_cnn, bench_conv,
                             bench_convergence, bench_e2train, bench_ft,
                             bench_kernels, bench_psg, bench_slu, bench_smd,
-                            bench_throughput, roofline)
+                            roofline)
 
     benches = {
         "smd": bench_smd.run,           # Fig. 3a/3b, Tab. 1
@@ -220,7 +204,6 @@ def main(argv=None) -> None:
         "kernels": bench_kernels.run,
         "conv": bench_conv.run,         # §Kernels (implicit-GEMM vs im2col)
         "attn": bench_attn.run,         # §Kernels (PSG flash bwd vs (S,T))
-        "throughput": bench_throughput.run,  # §Loop (chunked vs per-step)
         "roofline": roofline.run,       # §Roofline (from dry-run artifact)
         "audit": bench_audit.run,       # §Analysis (static cost audit)
         "ft": bench_ft.run,             # §Fault-tolerance (injected faults)

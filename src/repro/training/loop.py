@@ -28,6 +28,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.config import Experiment
 from repro.training.train_step import TrainState, make_train_step
@@ -129,6 +130,10 @@ def make_chunk_step(exp: Experiment, K: Optional[int] = None,
     return chunk_step
 
 
+# host span of one chunk's stacking, on the thread that runs the loop (chunk)
+STACK = "trainer.stack"
+
+
 def stack_batches(batches: Sequence[Dict[str, Any]]):
     """Stack per-step batches into the chunk's leading-K layout.
 
@@ -152,6 +157,8 @@ class ChunkPlanner:
     else ``None``.  ``flush`` returns the final partial chunk;
     ``flush_trailing`` returns drops after the last executed step (the
     caller advances the device step counter by that much once, at the end).
+    ``chunks`` counts the chunks emitted; each is stacked inside a
+    :data:`STACK` host span carrying its index.
     """
 
     def __init__(self, chunk_steps: int):
@@ -162,6 +169,7 @@ class ChunkPlanner:
         self._pending_drops = 0
         self.dropped = 0
         self.executed = 0
+        self.chunks = 0
 
     def add(self, step: int, batch):
         if batch is None:
@@ -197,7 +205,9 @@ class ChunkPlanner:
 
     def _emit(self):
         steps = tuple(self._steps)
-        batches = stack_batches(self._batches)
+        with TraceAnnotation(STACK, chunk=self.chunks):
+            batches = stack_batches(self._batches)
+        self.chunks += 1
         incs = np.asarray(self._incs, np.int32)
         self._steps, self._batches, self._incs = [], [], []
         return steps, batches, incs

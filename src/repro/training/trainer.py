@@ -39,6 +39,11 @@ Operational concerns of a long-running multi-pod job, in both modes:
   surfaces — the SMD machinery makes forced drops sound (DESIGN.md
   §Fault-tolerance).  On real multi-host deployments the deadline check
   runs per-host against the shared counter-based SMD schedule.
+
+Host spans (``jax.profiler.TraceAnnotation``, named in :data:`SPANS`) mark
+where the chunked loop and its data pipeline spend host time, so a
+profile labels each idle stretch of the device by the host work it
+waited on.  They cost one check per span when no profile is taken.
 """
 from __future__ import annotations
 
@@ -50,11 +55,26 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.config import Experiment
 from repro.core.smd import smd_keep_host
-from repro.training.loop import ChunkPlanner, make_chunk_step
+from repro.data.pipeline import MAKE_BATCH, SMD_DECIDE, DataPipeline
+from repro.training.loop import STACK, ChunkPlanner, make_chunk_step
 from repro.training.train_step import TrainState, make_train_step
+
+# Host spans of the chunked loop, main thread: one per phase per chunk, one
+# after another, so a profile labels an idle gap by the phase that covers
+# it (chunk: its index within the run).  Args are host ints already known.
+COLLECT = "trainer.collect"       # pull one chunk's items, queue waits
+                                  # included (chunk, first_step, last_step)
+DISPATCH = "trainer.dispatch"     # place + the chunk program call (chunk)
+SYNC = "trainer.sync"             # _finalize: device_get + history (chunk)
+CHECKPOINT = "trainer.checkpoint"  # a cadence save (chunk)
+# every host span, with STACK (stack_batches, opened by the ChunkPlanner
+# inside collect) and the pipeline thread's SMD_DECIDE and MAKE_BATCH (one
+# per nominal step, step)
+SPANS = (COLLECT, STACK, DISPATCH, SYNC, CHECKPOINT, SMD_DECIDE, MAKE_BATCH)
 
 
 class Trainer:
@@ -155,8 +175,6 @@ class Trainer:
 
     def _run_chunked(self, num_steps: int,
                      log_every: int = 0) -> List[Dict[str, float]]:
-        from repro.data.pipeline import DataPipeline
-
         planner = ChunkPlanner(self.chunk_steps)
         self._last_sync_t = 0.0
         start = int(self.state.step)
@@ -166,28 +184,29 @@ class Trainer:
         # one-chunk pipeline: while chunk N runs on device, chunk N+1 is
         # assembled from the prefetch queue and device_put (double-buffer);
         # chunk N's metrics sync when N+1 has been dispatched
-        in_flight = None                  # (steps, t0, device metrics)
+        in_flight = None          # (chunk, steps, t0, device metrics, smd)
+        remaining = num_steps
         try:
-            for _ in range(num_steps):
-                step, batch = next(pipe)
-                assert step == start + planner.executed + planner.dropped, \
-                    "pipeline out of lockstep with the SMD schedule"
-                if self._straggler_pending:
-                    # same contract as the per-step loop: each armed drop is
-                    # consumed by the NEXT step whatever it is — an SMD
-                    # drop absorbs it (one drop, not two); a kept step is
-                    # force-dropped (its prefetched batch is discarded)
-                    self._straggler_pending -= 1
-                    if batch is not None:
-                        planner.drop(step, batch)
-                        self.straggler_dropped_steps += 1
-                        continue
-                chunk = planner.add(step, batch)
+            while remaining:
+                first = start + planner.executed + planner.dropped
+                smd0 = (pipe.smd_decide_s, pipe.smd_decisions)
+                with TraceAnnotation(COLLECT, chunk=planner.chunks,
+                                     first_step=first) as ann:
+                    chunk = self._collect(pipe, planner, start, remaining)
+                    consumed = (start + planner.executed + planner.dropped
+                                - first)
+                    ann.set_metadata(last_step=first + consumed - 1)
+                remaining -= consumed
+                smd = [pipe.smd_decide_s - smd0[0],
+                       pipe.smd_decisions - smd0[1]]
                 if chunk is not None:
-                    in_flight = self._dispatch(chunk, in_flight, log_every)
-            tail = planner.flush()
-            if tail is not None:
-                in_flight = self._dispatch(tail, in_flight, log_every)
+                    in_flight = self._dispatch(chunk, planner.chunks - 1,
+                                               in_flight, smd, log_every)
+                elif in_flight is not None:
+                    # drops after the run's last executed step: counted
+                    # with the last chunk
+                    in_flight[4][0] += smd[0]
+                    in_flight[4][1] += smd[1]
             if in_flight is not None:
                 self._finalize(in_flight, log_every)
         finally:
@@ -234,14 +253,40 @@ class Trainer:
                 donate_argnums=donate)
         return self._chunk_fn
 
-    def _dispatch(self, chunk, in_flight, log_every):
-        """device_put + launch one chunk; sync the previous one after."""
+    def _collect(self, pipe, planner, start, remaining):
+        """Pull items until the planner emits a chunk or ``remaining``
+        nominal steps are consumed; returns the chunk (the run's partial
+        tail chunk in the latter case) or ``None``."""
+        for _ in range(remaining):
+            step, batch = next(pipe)
+            assert step == start + planner.executed + planner.dropped, \
+                "pipeline out of lockstep with the SMD schedule"
+            if self._straggler_pending:
+                # same contract as the per-step loop: each armed drop is
+                # consumed by the NEXT step whatever it is — an SMD
+                # drop absorbs it (one drop, not two); a kept step is
+                # force-dropped (its prefetched batch is discarded)
+                self._straggler_pending -= 1
+                if batch is not None:
+                    planner.drop(step, batch)
+                    self.straggler_dropped_steps += 1
+                    continue
+            chunk = planner.add(step, batch)
+            if chunk is not None:
+                return chunk
+        return planner.flush()
+
+    def _dispatch(self, chunk, ident, in_flight, smd, log_every):
+        """device_put + launch chunk number ``ident``; sync the previous
+        one after.  ``smd``: [seconds, count] of the SMD decisions the
+        chunk consumed."""
         steps, batches, incs = chunk
-        batches, incs = self.place(batches, incs)
-        with self._mesh_ctx():
-            t0 = time.perf_counter()
-            self.state, stacked = self.chunk_program()(self.state, batches,
-                                                       incs)
+        with TraceAnnotation(DISPATCH, chunk=ident):
+            batches, incs = self.place(batches, incs)
+            with self._mesh_ctx():
+                t0 = time.perf_counter()
+                self.state, stacked = self.chunk_program()(self.state,
+                                                           batches, incs)
         if in_flight is not None:
             self._finalize(in_flight, log_every)
         if self.ckpt_dir and self.ckpt_every and any(
@@ -250,8 +295,9 @@ class Trainer:
             # (np.asarray blocks) and lands on its boundary — its last
             # executed step — which is what resume derives the
             # chunk-aligned restart from (ft/checkpoint.resume_chunk_start)
-            self._save(steps[-1])
-        return steps, t0, stacked
+            with TraceAnnotation(CHECKPOINT, chunk=ident):
+                self._save(steps[-1])
+        return ident, steps, t0, stacked, smd
 
     def place(self, batches, incs):
         """Put one stacked chunk where the chunk program reads it: the
@@ -279,31 +325,35 @@ class Trainer:
     def _finalize(self, in_flight, log_every):
         """Chunk boundary: ONE host sync for the whole chunk's stacked
         metrics, then bookkeeping at chunk granularity."""
-        steps, t0, stacked = in_flight
-        host = jax.device_get(stacked)            # blocks until chunk done
-        sync_t = time.perf_counter()
-        # this chunk was dispatched (t0) while the PREVIOUS one was still
-        # running — clamp to the previous sync so overlapped time is not
-        # double-counted (else summed wall_s overstates wall clock ~2x and
-        # the straggler deadline trips on healthy chunks)
-        dt = sync_t - max(t0, self._last_sync_t)
-        self._last_sync_t = sync_t
-        per_step_s = dt / max(len(steps), 1)
-        for i, step in enumerate(steps):
-            metrics = {k: float(v[i]) for k, v in host.items()}
-            metrics["step"] = step
-            metrics["wall_s"] = per_step_s
-            self.history.append(metrics)
-            if log_every and step % log_every == 0:
-                print(f"step {step}: "
-                      f"loss={metrics.get('total_loss', 0):.4f} "
-                      f"({per_step_s*1e3:.0f} ms)")
-        if self.deadline_s and not self._arm_stragglers(steps, sync_t):
-            # no device-side timestamps arrived (callback not yet flushed or
-            # instrumentation unavailable): fall back to the pre-PR 10
-            # chunk-mean check so a straggling chunk still arms one drop
-            if per_step_s > self.deadline_s:
-                self._straggler_pending += 1
+        ident, steps, t0, stacked, smd = in_flight
+        with TraceAnnotation(SYNC, chunk=ident):
+            host = jax.device_get(stacked)        # blocks until chunk done
+            sync_t = time.perf_counter()
+            # this chunk was dispatched (t0) while the PREVIOUS one was
+            # still running — clamp to the previous sync so overlapped time
+            # is not double-counted (else summed wall_s overstates wall
+            # clock ~2x and the straggler deadline trips on healthy chunks)
+            dt = sync_t - max(t0, self._last_sync_t)
+            self._last_sync_t = sync_t
+            per_step_s = dt / len(steps)
+            for i, step in enumerate(steps):
+                metrics = {k: float(v[i]) for k, v in host.items()}
+                metrics["step"] = step
+                metrics["wall_s"] = per_step_s
+                # the SMD decisions the chunk consumed, spread like wall_s
+                metrics["smd_decide_s"] = smd[0] / len(steps)
+                metrics["smd_decisions"] = smd[1] / len(steps)
+                self.history.append(metrics)
+                if log_every and step % log_every == 0:
+                    print(f"step {step}: "
+                          f"loss={metrics.get('total_loss', 0):.4f} "
+                          f"({per_step_s*1e3:.0f} ms)")
+            if self.deadline_s and not self._arm_stragglers(steps, sync_t):
+                # no device-side timestamps arrived (callback not yet
+                # flushed or instrumentation unavailable): fall back to the
+                # chunk-mean check so a straggling chunk still arms one drop
+                if per_step_s > self.deadline_s:
+                    self._straggler_pending += 1
 
     def _record_step_time(self, step) -> None:
         """Ordered-callback target: one timestamp per scanned step, keyed by
