@@ -9,6 +9,15 @@ mitigation (DESIGN.md §7): a pod that would miss the step deadline declares
 the step dropped, and because SMD-style sampling-with-replacement is exactly
 what the training dynamics already tolerate, convergence is unaffected.
 
+The host's decisions (``smd_keep_host``, the data pipeline's thread, the
+per-step loop, ``SMDIterator``, ``smd_schedule``) run ``smd_keep`` itself,
+vmapped over blocks of consecutive steps on the host's CPU backend and
+cached per block, never on the accelerator: there each decision would be
+a few tiny device programs queued behind the running chunk program, and
+the pipeline would wait on the chip for a scalar.  Threefry and the uniform
+conversion are integer and exact-float operations, so the CPU's decisions
+are bit for bit the accelerator's.
+
 ``equivalent_steps`` maps a full-training iteration budget to the number of
 *executed* steps under SMD; the paper's adopted operating point is energy
 ratio 0.67 (i.e. SMD with 2x the nominal epochs costs 0.67x the energy but
@@ -16,6 +25,7 @@ reaches higher accuracy than the standard protocol, Fig. 3a).
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -31,9 +41,43 @@ def smd_keep(seed: int, step, drop_prob: float):
     return jax.random.uniform(key) >= drop_prob
 
 
+# steps per host decision block: a block costs one small CPU program, and a
+# chunk of the compiled loop needs about 8 decisions
+_BLOCK = 256
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def _keep_block(seed: int, steps, drop_prob: float):
+    """``smd_keep`` over a vector of steps; ``seed`` is static so that
+    ``PRNGKey(seed)`` is built exactly as in ``smd_keep`` (seeds >= 2**31
+    included)."""
+    return jax.vmap(lambda s: smd_keep(seed, s, drop_prob))(steps)
+
+
+def _decide_block(seed: int, drop_prob: float, block: int) -> jax.Array:
+    """Keep decisions of steps ``block*_BLOCK .. (block+1)*_BLOCK - 1``,
+    computed on the CPU backend, or on the default device in a process
+    that has no CPU backend."""
+    try:
+        device = jax.devices("cpu")[0]
+    except RuntimeError:                    # no CPU backend in this process
+        device = None
+    steps = np.arange(block * _BLOCK, (block + 1) * _BLOCK, dtype=np.uint32)
+    return _keep_block(seed, jax.device_put(steps, device), drop_prob)
+
+
+@functools.lru_cache(maxsize=64)
+def _host_block(seed: int, drop_prob: float, block: int) -> np.ndarray:
+    keep = np.array(_decide_block(seed, drop_prob, block))
+    keep.flags.writeable = False            # shared by every caller
+    return keep
+
+
 def smd_keep_host(seed: int, step: int, drop_prob: float) -> bool:
-    """Host-side (non-traced) version: decides whether to even fetch data."""
-    return bool(np.asarray(smd_keep(seed, int(step), drop_prob)))
+    """Host-side (non-traced) version: decides whether to even fetch data.
+    Read from a cached block of ``smd_keep``'s decisions on the host CPU."""
+    block, i = divmod(int(step), _BLOCK)
+    return bool(_host_block(seed, drop_prob, block)[i])
 
 
 def smd_schedule(cfg: SMDConfig, seed: int, total_steps: int) -> np.ndarray:

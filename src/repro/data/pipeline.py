@@ -3,8 +3,9 @@
 At scale each host generates/loads only its shard of the global batch (the
 synthetic generators are counter-based so shards never overlap).  A small
 background thread keeps ``prefetch`` batches ready; SMD drops are decided
-*before* generation, so a dropped step costs nothing — the zero-overhead
-property the paper's data-level technique relies on.
+*before* generation, on the host's CPU (``core/smd.smd_keep_host``), so a
+dropped step costs nothing — the zero-overhead property the paper's
+data-level technique relies on.
 
 The pipeline keeps the seconds and the count of the SMD decisions behind
 the items it has handed out (``smd_decide_s``, ``smd_decisions``), and

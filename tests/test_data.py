@@ -143,6 +143,22 @@ def test_pipeline_resume_matches_schedule_tail():
     assert sum(1 for k in got_kept if not k) == int((~sched[start:]).sum())
 
 
+def test_pipeline_counts_one_smd_decision_per_nominal_step():
+    """Across a decision-block edge (step 256), the pipeline still counts
+    one timed decision per nominal step handed out, dropped or kept."""
+    cfg = SMDConfig(enabled=True, drop_prob=0.5)
+    seed, start, n = 11, 250, 12
+    mk = lambda step, shard: {"x": np.full((2,), step)}
+    pipe = DataPipeline(mk, cfg, seed=seed, start_step=start)
+    out = [next(pipe) for _ in range(n)]
+    pipe.close()
+    assert [s for s, _ in out] == list(range(start, start + n))
+    assert [b is not None for _, b in out] == \
+        [bool(k) for k in smd_schedule(cfg, seed, start + n)[start:]]
+    assert pipe.smd_decisions == n
+    assert pipe.smd_decide_s > 0
+
+
 def test_smd_iterator_resume_matches_schedule_tail():
     """SMDIterator at start_step > 0: same tail reproduction, and the
     underlying iterator advances only on kept steps (zero-overhead drops).

@@ -7,10 +7,12 @@ import pytest
 pytest.importorskip("hypothesis")   # optional dev dep (requirements-dev.txt)
 from hypothesis import given, settings, strategies as st
 
+from bench.families.cifar_cnn import TRAIN_SEED
+from repro.core import smd as smd_mod
 from repro.core.config import (E2TrainConfig, Experiment, ModelConfig,
                                SMDConfig, TrainConfig)
-from repro.core.smd import (SMDIterator, expected_energy_ratio, smd_keep_host,
-                            smd_schedule)
+from repro.core.smd import (SMDIterator, expected_energy_ratio, smd_keep,
+                            smd_keep_host, smd_schedule)
 
 
 @settings(max_examples=20, deadline=None)
@@ -20,6 +22,28 @@ def test_smd_decision_deterministic(seed, step):
     a = smd_keep_host(seed, step, 0.5)
     b = smd_keep_host(seed, step, 0.5)
     assert a == b
+
+
+# either side of the first block edge, and a step near the int32 limit
+PARITY_STEPS = (0, 255, 256, 257, 2 ** 31 - 2)
+
+
+@pytest.mark.parametrize("drop_prob", [0.5, 0.3])
+@pytest.mark.parametrize("seed", sorted({0, TRAIN_SEED, 2 ** 31 - 1,
+                                         2 ** 31 + 5}))
+@pytest.mark.parametrize("step", PARITY_STEPS)
+def test_host_decision_matches_traced_smd_keep(seed, drop_prob, step):
+    """The host's cached CPU blocks give ``smd_keep``'s decision on the
+    default device, bit for bit, seeds >= 2**31 included."""
+    want = bool(jax.jit(smd_keep, static_argnums=(0, 2))(seed, step,
+                                                         drop_prob))
+    assert smd_keep_host(seed, step, drop_prob) is want
+    if step < 2 ** 16:
+        sched = smd_schedule(SMDConfig(enabled=True, drop_prob=drop_prob),
+                             seed, step + 1)
+        assert bool(sched[step]) is want
+    block = smd_mod._decide_block(seed, drop_prob, step // smd_mod._BLOCK)
+    assert {d.platform for d in block.devices()} == {"cpu"}
 
 
 def test_smd_drop_rate():
