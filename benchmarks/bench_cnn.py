@@ -92,7 +92,7 @@ def run(fast: bool = True) -> List[str]:
     pmv, smv = R.init_mobilenetv2(jax.random.PRNGKey(2))
     b = make_image_batch(TASK, 0, 0, 0, 8)
     t0 = time.perf_counter()
-    logits, _ = R.mobilenetv2_fwd(pmv, smv, b["image"])
+    logits, _, _ = R.mobilenetv2_fwd(pmv, smv, b["image"])
     wallm = (time.perf_counter() - t0) * 1e6
     rows.append(csv_row("tab4/mobilenetv2_fwd", wallm,
                         f"logits_finite={bool(np.isfinite(np.asarray(logits)).all())}"))

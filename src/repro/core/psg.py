@@ -49,24 +49,39 @@ import jax.numpy as jnp
 from repro.core.config import PSGConfig
 from repro.core.quant import msb_of, quantize, quantize_int
 from repro.kernels import dispatch
+from repro.kernels.psg_depthwise import depthwise_conv
 from repro.kernels.ref import (predictor_confidence_ref,  # test-only oracle
                                psg_grad_w_ref)            # (re-exports)
 
-# (PROBE_FALLBACK_MACS, PROBE_TOTAL_MACS) slots of the probe vector: each
-# PSG matmul's backward contributes [fallback_ratio * macs, macs], so the
+# Slots of the probe vector.  Each PSG matmul / conv / attention backward
+# contributes [fallback_ratio * macs, macs] to slots 0-1, so the
 # accumulated ratio is MAC-weighted — a tiny all-fallback layer cannot
 # swamp a huge mostly-predicted one (the energy model charges MACs, so
-# MACs are the right weight).
-PROBE_SIZE = 2
+# MACs are the right weight).  Each PSG depthwise backward contributes
+# [entries below tau, entries] of its (9, C) weight gradient to slots 2-3.
+PROBE_SIZE = 4
 
 
 def zero_probe() -> jnp.ndarray:
     return jnp.zeros((PROBE_SIZE,), jnp.float32)
 
 
+def _probe_entry(slot: int, fallback, weight) -> jnp.ndarray:
+    """A probe cotangent carrying ``[fallback * weight, weight]`` in the
+    slot pair starting at ``slot``."""
+    pair = jnp.stack([fallback * weight, jnp.float32(weight)])
+    return jnp.pad(pair, (slot, PROBE_SIZE - slot - 2))
+
+
 def probe_fallback_ratio(probe_grad: jnp.ndarray) -> jnp.ndarray:
     """MAC-weighted measured fallback ratio from a probe cotangent."""
     return probe_grad[0] / jnp.maximum(probe_grad[1], 1.0)
+
+
+def probe_dw_fallback_ratio(probe_grad: jnp.ndarray) -> jnp.ndarray:
+    """Share of the depthwise weight-gradient entries, over every PSG
+    depthwise conv of the step, whose predictor fell below ``tau``."""
+    return probe_grad[2] / jnp.maximum(probe_grad[3], 1.0)
 
 
 # ---------------------------------------------------------------------------
@@ -149,8 +164,7 @@ def _psg_bwd_impl(cfg, res, gy):
     sign, fallback = dispatch.psg_grad_w(x2, gy, cfg)
     dw = sign.astype(w.dtype)
     macs = jnp.float32(x2.shape[0]) * x2.shape[1] * gy.shape[1]
-    dprobe = jnp.stack([fallback * macs, macs])
-    return dx, dw, dprobe
+    return dx, dw, _probe_entry(0, fallback, macs)
 
 
 _psg_matmul.defvjp(_psg_fwd, _psg_bwd)
@@ -224,11 +238,66 @@ def _psg_conv2d_bwd_impl(k, stride, cfg, res, gy):
     sign, fallback = dispatch.conv_grad_w(xp, gy, cfg, k=k, stride=stride)
     dw = sign.astype(w.dtype)
     macs = jnp.float32(B * ho * wo) * (k * k * C) * dout
-    dprobe = jnp.stack([fallback * macs, macs])
-    return dxp.astype(xp.dtype), dw, dprobe
+    return dxp.astype(xp.dtype), dw, _probe_entry(0, fallback, macs)
 
 
 _psg_conv2d.defvjp(_psg_conv2d_fwd, _psg_conv2d_bwd)
+
+
+# ---------------------------------------------------------------------------
+# 3x3 depthwise convolution (MobileNetV2's inverted residuals)
+# ---------------------------------------------------------------------------
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _psg_depthwise(x: jnp.ndarray, w: jnp.ndarray, probe: jnp.ndarray,
+                   stride: int, cfg: PSGConfig) -> jnp.ndarray:
+    """3x3 SAME depthwise conv ``(B, H, W, C) x (9, C)`` with PSG semantics.
+
+    Forward: the input and the weight on the ``bits_x`` grid (per-tensor
+    codes; the taps are windows of the quantized input, so they share its
+    grid), the windows strided before the multiply-sum, in XLA.  The
+    backward's weight gradient is Eq. (2) per ``(tap, channel)`` entry
+    through the depthwise kernel (``kernels/psg_depthwise.py``, via the
+    dispatch layer); ``probe`` carries its fallback share (module
+    docstring).
+    """
+    xq = quantize(x, cfg.bits_x)
+    wq = quantize(w, cfg.bits_x).astype(xq.dtype)
+    return depthwise_conv(xq, wq, stride)
+
+
+def _psg_depthwise_fwd(x, w, probe, stride, cfg):
+    return _psg_depthwise(x, w, probe, stride, cfg), (x, w)
+
+
+def _psg_depthwise_bwd(stride, cfg, res, gy):
+    with jax.named_scope("precision:psg_depthwise_bwd"):
+        x, w = res
+        gq = quantize(gy, cfg.bits_g)
+        wq = quantize(w, cfg.bits_x).astype(gq.dtype)
+        # input gradient: the transposed depthwise conv of the quantized
+        # output gradient with the quantized weight, in XLA
+        (dx,) = jax.linear_transpose(
+            lambda a: depthwise_conv(a, wq, stride),
+            jax.ShapeDtypeStruct(x.shape, gq.dtype))(gq)
+        sign, fallback = dispatch.psg_depthwise_grad_w(x, gy, cfg,
+                                                       stride=stride)
+        return (dx.astype(x.dtype), sign.astype(w.dtype),
+                _probe_entry(2, fallback, w.size))
+
+
+_psg_depthwise.defvjp(_psg_depthwise_fwd, _psg_depthwise_bwd)
+
+
+def depthwise(x: jnp.ndarray, w: jnp.ndarray, *, stride: int = 1
+              ) -> jnp.ndarray:
+    """PSG depthwise conv under the active config and stats probe; model
+    code calls it only while a PSG context is active."""
+    cfg = active_config()
+    if cfg is None:
+        raise RuntimeError("psg.depthwise needs an active PSG context")
+    return _psg_depthwise(x, w, _current_probe(), stride, cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -275,8 +344,8 @@ def _psg_attention_bwd_impl(causal, cfg, res, gy):
     # triangle is skipped); x2 for the dv and dk contractions
     pairs = S * (S + 1) // 2 if (causal and S == T) else S * T
     macs = jnp.float32(2 * B * nh * hd) * pairs
-    dprobe = jnp.stack([fallback * macs, macs])
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), dprobe
+    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
+            _probe_entry(0, fallback, macs))
 
 
 _psg_attention.defvjp(_psg_attention_fwd, _psg_attention_bwd)
@@ -370,10 +439,11 @@ def _current_probe() -> jnp.ndarray:
 def enable(cfg: Optional[PSGConfig], probe: Optional[jnp.ndarray] = None):
     """Route model matmuls through PSG while tracing under this context.
 
-    ``probe``: an optional zeros((2,)) array threaded into every PSG matmul;
-    differentiate the enclosing loss w.r.t. it to read the accumulated
-    [sum of fallback_ratio * macs, sum of macs] — the measured MAC-weighted
-    per-step ``psg_fallback_ratio`` (see training/train_step.py).
+    ``probe``: an optional ``zero_probe()`` array threaded into every PSG
+    op; differentiate the enclosing loss w.r.t. it to read the accumulated
+    [sum of fallback_ratio * macs, sum of macs, depthwise entries below
+    tau, depthwise entries] — the measured per-step ``psg_fallback_ratio``
+    and ``psg_dw_fallback_ratio`` (see training/train_step.py).
     """
     prev = getattr(_state, "cfg", None)
     prev_probe = getattr(_state, "probe", None)

@@ -148,6 +148,25 @@ def psg_grad_w(x2: jnp.ndarray, gy2: jnp.ndarray, cfg: PSGConfig
                           mesh=mesh if axes else None, axes=axes)
 
 
+def psg_depthwise_grad_w(x: jnp.ndarray, gy: jnp.ndarray, cfg: PSGConfig,
+                         *, stride: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """PSG weight gradient of a 3x3 depthwise conv: ``(sign (9, C),
+    fallback)``, the fallback the share of ``(tap, channel)`` entries
+    whose predictor fell below the threshold, on every backend.
+
+    The depthwise kernel (``kernels/psg_depthwise.py``) on the
+    interpret/mosaic backends; the element-level oracle on the reference
+    backend.  ``x`` is the conv's NHWC input, ``gy`` its output gradient.
+    """
+    backend = resolve_backend(cfg)
+    xf = x.astype(jnp.float32)
+    gf = gy.astype(jnp.float32)
+    if backend == BACKEND_REFERENCE:
+        return ref.psg_depthwise_grad_w_ref(xf, gf, cfg, stride)
+    return ops.psg_depthwise_grad_w(xf, gf, cfg, stride,
+                                    interpret=backend != BACKEND_MOSAIC)
+
+
 def quantize(x: jnp.ndarray, bits: int,
              cfg: Optional[PSGConfig] = None) -> jnp.ndarray:
     """Fake-quantize through the backend the context resolves to."""
@@ -300,7 +319,8 @@ def shipped_kernels() -> Dict[str, Tuple[Callable, tuple]]:
     Shapes are chosen so every grid has more than one step along each axis
     the kernel tiles.
     """
-    from repro.kernels import conv, flash_attn, psg_matmul, quant
+    from repro.kernels import (conv, flash_attn, psg_depthwise, psg_matmul,
+                               quant)
 
     f32 = jnp.float32
     i8 = jnp.int8
@@ -332,6 +352,19 @@ def shipped_kernels() -> Dict[str, Tuple[Callable, tuple]]:
             lambda a, b: psg_matmul.predictor_matmul_pallas(
                 a, b, interpret=True),
             (S((1024, 256), i8), S((1024, 256), i16))),
+        # PSG depthwise: C=192 is not a multiple of 128 (one channel tile
+        # as wide as the array), M=2048 rows in 4 row tiles; C=256 in two
+        # 128-lane tiles
+        "psg_depthwise_products": (
+            lambda a, b, c, d: psg_depthwise.psg_depthwise_products(
+                a, b, c, d, bm=512, interpret=True),
+            (S((9, 2048, 192), i8), S((2048, 192), i16),
+             S((9, 2048, 192), i8), S((2048, 192), i16))),
+        "psg_depthwise_products[lanes]": (
+            lambda a, b, c, d: psg_depthwise.psg_depthwise_products(
+                a, b, c, d, bm=512, interpret=True),
+            (S((9, 2048, 256), i8), S((2048, 256), i16),
+             S((9, 2048, 256), i8), S((2048, 256), i16))),
         "quantize_pallas": (
             functools.partial(quant.quantize_pallas, bits=8, interpret=True),
             (S((512, 1024), f32,),)),
@@ -406,6 +439,7 @@ def kernel_acc_dtypes() -> Dict[str, str]:
     """
     return {
         "predictor_matmul_pallas": "float32",
+        "psg_depthwise_products": "float32",
         "quantize_pallas": "float32",
         "flash_attention": "float32",
         "flash_bwd_dq_pallas": "float32",

@@ -25,6 +25,7 @@ from repro.core.config import PSGConfig
 from repro.core.quant import qscale
 from repro.kernels import conv as _cv
 from repro.kernels import flash_attn as _fa
+from repro.kernels import psg_depthwise as _dw
 from repro.kernels import psg_matmul as _pm
 from repro.kernels import quant as _q
 
@@ -75,6 +76,31 @@ def psg_grad_w(x2: jnp.ndarray, gy2: jnp.ndarray, cfg: PSGConfig,
         prods = products(*codes)
     sign, tiles = _pm.psg_select(*prods, cfg.beta)
     return sign, jnp.mean(tiles.astype(jnp.float32))
+
+
+@partial(jax.jit, static_argnames=("cfg", "stride", "interpret"))
+def psg_depthwise_grad_w(x: jnp.ndarray, gy: jnp.ndarray, cfg: PSGConfig,
+                         stride: int, interpret: bool = True
+                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """PSG weight gradient of a 3x3 depthwise conv.
+
+    ``x``: the conv's NHWC input ``(B, H, W, C)``; ``gy``: its output
+    gradient ``(B, H', W', C)``.  The codes are made element-wise on
+    ``x`` and ``gy`` (per-tensor scales, as :func:`psg_grad_w` makes
+    them), the tap stacks are gathered from the codes in XLA, and both
+    code products run in one pass of the kernel
+    (``kernels/psg_depthwise.py``).  Returns ``(sign (9, C) float32 in
+    {-1, 0, +1}, fallback)``: the share of ``(tap, channel)`` entries
+    below the predictor's threshold.
+    """
+    C = x.shape[-1]
+    g2 = gy.reshape(-1, C)
+    taps = [_dw.depthwise_taps(_codes(x, b)[0], stride)
+            for b in (cfg.bits_x_msb, cfg.bits_x)]
+    g_msb, g_full = _dw.psg_depthwise_products(
+        taps[0], _codes(g2, cfg.bits_g_msb)[0], taps[1],
+        _codes(g2, cfg.bits_g)[0], interpret=interpret)
+    return _dw.psg_depthwise_select(g_msb, g_full, cfg.beta)
 
 
 @partial(jax.jit, static_argnames=("bits", "interpret"))

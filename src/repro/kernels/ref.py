@@ -129,6 +129,35 @@ def conv_fallback_ratio_ref(xp: jnp.ndarray, gy: jnp.ndarray, cfg: PSGConfig,
     return psg_fallback_ratio_ref(p2, gy.reshape(-1, gy.shape[-1]), cfg)
 
 
+# ---------------------------------------------------------------------------
+# depthwise oracle (kernels/psg_depthwise.py)
+# ---------------------------------------------------------------------------
+
+
+def psg_depthwise_grad_w_ref(x: jnp.ndarray, gy: jnp.ndarray,
+                             cfg: PSGConfig, stride: int) -> tuple:
+    """Element-level Eq. (2) of a depthwise weight gradient: ``(sign (9, C)
+    float32, fallback share of entries below tau)``.  Both products in
+    code units, ``sum_m codes(P_t)[m, c] * codes(gy)[m, c]``, the codes
+    made on ``x`` before the windows are gathered."""
+    from repro.kernels.psg_depthwise import depthwise_taps
+    g2 = gy.reshape(-1, x.shape[-1])
+
+    def codes(a, bits):
+        return quantize_int(a, bits)[0].astype(jnp.float32)
+
+    def product(bx, bg):
+        taps = depthwise_taps(codes(x, bx), stride)
+        return jnp.sum(taps * codes(g2, bg)[None], axis=1)
+
+    g_msb = product(cfg.bits_x_msb, cfg.bits_g_msb)
+    g_full = product(cfg.bits_x, cfg.bits_g)
+    tau = cfg.beta * jnp.max(jnp.abs(g_msb))
+    conf = jnp.abs(g_msb) >= tau
+    return (jnp.where(conf, jnp.sign(g_msb), jnp.sign(g_full)),
+            jnp.mean(jnp.logical_not(conf).astype(jnp.float32)))
+
+
 def psg_grad_w_oracle(x2: jnp.ndarray, gy2: jnp.ndarray, cfg: PSGConfig
                       ) -> jnp.ndarray:
     """Element-level Eq. (2) — identical semantics to the tile-level kernel:

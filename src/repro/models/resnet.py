@@ -3,8 +3,11 @@
 These are the models the paper actually trains (§4.1): CIFAR-style ResNets
 (6n+2 layers; n=12 -> 74, n=18 -> 110, [He et al. 2016]) and MobileNetV2
 scaled for 32x32 inputs.  E²-Train hooks are identical to the transformer
-path: SLU gates every residual block (the paper's granularity), PSG routes
-the conv-as-matmul weight gradients, SMD lives in the data pipeline.
+path: SLU gates every identity-shortcut block (the paper's granularity;
+ResNet's projection transitions and MobileNetV2's stride-2 or widening
+blocks always run), PSG routes the conv-as-matmul weight gradients and,
+in MobileNetV2, the depthwise weight gradients (``psg.depthwise``), SMD
+lives in the data pipeline.
 
 Convs are implemented as im2col + ``psg.matmul`` so the PSG custom-vjp (and
 later the Pallas kernel) applies to the conv backward exactly as the paper's
@@ -39,6 +42,7 @@ from jax import lax
 
 from repro.core import psg, slu
 from repro.core.config import E2TrainConfig
+from repro.kernels.psg_depthwise import depthwise_conv
 from repro.models.layers import dense_init
 
 Params = Dict[str, Any]
@@ -187,6 +191,27 @@ def _block_branch(blk: Params, bst: Params, h: jnp.ndarray, stride: int,
     return y, {"bn1": ns1, "bn2": ns2}
 
 
+def _slu_skip(branch, h, bst, gate_params, gst, glob, force, e2, rng):
+    """One identity-shortcut block under the SLU gate: the gate's keep
+    probability from the block input, a Bernoulli draw folded from the
+    block index ``glob`` (or ``force``), and ``lax.cond`` between
+    ``h + g_st * branch`` and the identity.  ``branch(h, bst)`` returns
+    (branch output, new BN state).  Returns (h, new_bn_state,
+    new_gate_state, keep_prob, executed)."""
+    pkeep, gst = slu.gate_apply(gate_params, h, gst, e2.slu)
+    brng = jax.random.fold_in(rng, glob)
+    keep = jax.random.bernoulli(brng, pkeep) | force
+    g_st = 1.0 + pkeep - lax.stop_gradient(pkeep)   # straight-through factor
+
+    def run(op):
+        h, bst = op
+        y, nbst = branch(h, bst)
+        return h + g_st * y, nbst
+
+    h, nbst = lax.cond(keep, run, lambda op: op, (h, bst))
+    return h, nbst, gst, pkeep, keep.astype(jnp.float32)
+
+
 def _gated_block(blk, bst, h, gate_params, gst, glob, n_blocks, e2, rng,
                  train: bool, slu_on: bool):
     """Stride-1 identity-shortcut block, SLU-gated when ``slu_on``.
@@ -198,20 +223,12 @@ def _gated_block(blk, bst, h, gate_params, gst, glob, n_blocks, e2, rng,
         y, nbst = _block_branch(blk, bst, h, 1, train)
         return (jax.nn.relu(h + y), nbst, gst,
                 jnp.float32(1.0), jnp.float32(1.0))
-    pkeep, gst = slu.gate_apply(gate_params, h, gst, e2.slu)
-    brng = jax.random.fold_in(rng, glob)
     force = ((glob == 0) | (glob == n_blocks - 1)) \
         if e2.slu.never_skip_first_last else jnp.bool_(False)
-    keep = jax.random.bernoulli(brng, pkeep) | force
-    g_st = 1.0 + pkeep - lax.stop_gradient(pkeep)   # straight-through factor
-
-    def run(op):
-        h, bst = op
-        y, nbst = _block_branch(blk, bst, h, 1, train)
-        return h + g_st * y, nbst
-
-    h, nbst = lax.cond(keep, run, lambda op: op, (h, bst))
-    return jax.nn.relu(h), nbst, gst, pkeep, keep.astype(jnp.float32)
+    h, nbst, gst, pkeep, ex = _slu_skip(
+        lambda h, bst: _block_branch(blk, bst, h, 1, train), h, bst,
+        gate_params, gst, glob, force, e2, rng)
+    return jax.nn.relu(h), nbst, gst, pkeep, ex
 
 
 def _transition_block(sp, ss, h, stage, gate_params, gst, glob, n_blocks,
@@ -358,7 +375,12 @@ def resnet_loss(p: Params, state: Params, batch, depth: int, e2=None,
     e2 = e2 or E2TrainConfig()
     logits, aux, new_state = fwd(p, state, batch["image"], depth, e2, rng,
                                  train=train)
-    labels = batch["label"]
+    return _classifier_loss(logits, aux, batch["label"], e2, new_state)
+
+
+def _classifier_loss(logits, aux, labels, e2: E2TrainConfig, new_state):
+    """Cross-entropy + ``alpha`` x the mean keep probability (Eq. 1) and
+    the SLU metrics, in the task-registry loss contract."""
     logp = jax.nn.log_softmax(logits)
     nll = -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=-1))
     total = nll + (e2.slu.alpha * aux["slu_cost"] if e2.slu.enabled else 0.0)
@@ -368,7 +390,7 @@ def resnet_loss(p: Params, state: Params, batch, depth: int, e2=None,
 
 
 # ---------------------------------------------------------------------------
-# MobileNetV2 (CIFAR variant)
+# MobileNetV2 (CIFAR variant: stride-1 stem and first 24-channel stage)
 # ---------------------------------------------------------------------------
 
 MBV2_CFG = [  # (expansion, cout, blocks, stride)
@@ -388,8 +410,20 @@ def _mbv2_layout() -> List[Tuple[int, int, int, int, bool]]:
     return out
 
 
-def init_mobilenetv2(key, num_classes: int = 10) -> Tuple[Params, Params]:
-    """Returns (params, state): state is the BatchNorm running-stat tree."""
+def mbv2_gate_width() -> int:
+    """Width of the SLU gate's pooled input: the widest input of an
+    identity-shortcut block (narrower inputs are zero-padded to it)."""
+    return max(cin for cin, _h, _c, _s, res in _mbv2_layout() if res)
+
+
+def init_mobilenetv2(key, num_classes: int = 10,
+                     e2: Optional[E2TrainConfig] = None
+                     ) -> Tuple[Params, Params]:
+    """Returns (params, state): state is the BatchNorm running-stat tree.
+    With SLU on, ``slu_gate`` is one weight-shared gate over the identity
+    blocks' inputs (its key is drawn after every other, so the other
+    weights do not depend on SLU)."""
+    e2 = e2 or E2TrainConfig()
     keys = jax.random.split(key, 64)
     ki = iter(range(64))
     p: Params = {"stem": init_conv(keys[next(ki)], 3, 32), "stem_bn": init_bn(32),
@@ -412,49 +446,88 @@ def init_mobilenetv2(key, num_classes: int = 10) -> Tuple[Params, Params]:
     s["head_bn"] = init_bn_state(1280)
     p["fc_w"] = dense_init(keys[next(ki)], (1280, num_classes), jnp.float32)
     p["fc_b"] = jnp.zeros((num_classes,))
+    if e2.slu.enabled:
+        p["slu_gate"] = slu.init_gate(keys[next(ki)], mbv2_gate_width(),
+                                      e2.slu)
     return p, s
 
 
 def _depthwise(w: jnp.ndarray, x: jnp.ndarray, stride: int) -> jnp.ndarray:
-    """3x3 depthwise conv: stride applied to the patch stack *before* the
-    multiply-sum, so a stride-2 block computes a quarter of the products
-    instead of computing full resolution and slicing the result."""
-    B, H, W, C = x.shape
-    xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
-    cols = []
-    for i in range(3):
-        for j in range(3):
-            cols.append(xp[:, i:i + H:stride, j:j + W:stride, :])
-    stack = jnp.stack(cols, axis=-2)                 # (B,H',W',9,C)
-    return jnp.sum(stack * w[None, None, None], axis=-2)
+    """3x3 depthwise conv (``kernels/psg_depthwise.depthwise_conv``: the
+    stride applied to the windows before the multiply-sum).  With an
+    active PSG context it runs through ``psg.depthwise``: the forward on
+    the ``bits_x`` grid, the weight gradient by Eq. (2) in the PSG
+    depthwise kernel."""
+    if psg.active_config() is not None:
+        return psg.depthwise(x, w, stride=stride)
+    return depthwise_conv(x, w, stride)
+
+
+def _mbv2_branch(blk: Params, bst: Params, h: jnp.ndarray, stride: int,
+                 train: bool, i: int) -> Tuple[jnp.ndarray, Params]:
+    """Inverted-residual branch: 1x1 expand-BN-relu6, 3x3 depthwise-BN-
+    relu6, linear 1x1 project-BN; returns (branch, new bn state)."""
+    y, ns1 = batchnorm(blk["bn1"], bst["bn1"],
+                       conv2d(blk["expand"], h, k=1), train)
+    y = jax.nn.relu6(y)
+    # nested scopes: the dw tag is innermost, so the audit walker
+    # attributes the depthwise multiply-sum separately from the
+    # block's 1x1 expand/project convs (their MAC models differ).
+    with jax.named_scope(f"cost:b{i}.dw"):
+        y = _depthwise(blk["dw"], y, stride)
+    y, ns2 = batchnorm(blk["bn2"], bst["bn2"], y, train)
+    y = jax.nn.relu6(y)
+    y, ns3 = batchnorm(blk["bn3"], bst["bn3"],
+                       conv2d(blk["project"], y, k=1), train)
+    return y, {"bn1": ns1, "bn2": ns2, "bn3": ns3}
 
 
 def mobilenetv2_fwd(p: Params, state: Params, x: jnp.ndarray,
-                    train: bool = True) -> Tuple[jnp.ndarray, Params]:
-    """Returns (logits, new running-stat state)."""
+                    e2: Optional[E2TrainConfig] = None,
+                    rng: Optional[jnp.ndarray] = None,
+                    train: bool = True
+                    ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray], Params]:
+    """x: (B, 32, 32, 3) -> (logits, aux{slu_*}, new running-stat state).
+
+    The 17 blocks are unrolled (their widths differ).  With SLU on (and
+    ``train``), the 10 identity-shortcut blocks are gated as ResNet's are
+    (:func:`_slu_skip`: the weight-shared gate, a Bernoulli draw folded
+    from the block index, ``never_skip_first_last`` on the first and last
+    *gated* block); the 7 blocks without an identity shortcut always run
+    and report executed 1.  ``aux`` holds one keep probability and one
+    executed flag per block, 17 each.
+    """
+    e2 = e2 or E2TrainConfig()
+    rng = rng if rng is not None else jax.random.PRNGKey(0)
+    slu_on = e2.slu.enabled and train and "slu_gate" in p
+    gate_params = p.get("slu_gate")
+    layout = _mbv2_layout()
+    gated = [i for i, blk in enumerate(layout) if blk[4]]
+    ends = (gated[0], gated[-1]) if e2.slu.never_skip_first_last else ()
     with jax.named_scope("cost:stem"):
         h, ns_stem = batchnorm(p["stem_bn"], state["stem_bn"],
                                conv2d(p["stem"], x), train)
         h = jax.nn.relu6(h)
+    gst = slu.init_gate_state(e2.slu)
     new_state: Params = {"stem_bn": ns_stem, "blocks": []}
+    kps, exs = [], []
     for i, (blk, bst, (_cin, _hid, _c, stride, residual)) in enumerate(zip(
-            p["blocks"], state["blocks"], _mbv2_layout())):
-        # nested scopes: the dw tag is innermost, so the audit walker
-        # attributes the depthwise multiply-sum separately from the
-        # block's 1x1 expand/project convs (their MAC models differ).
+            p["blocks"], state["blocks"], layout)):
         with jax.named_scope(f"cost:b{i}"):
-            inp = h
-            y, ns1 = batchnorm(blk["bn1"], bst["bn1"],
-                               conv2d(blk["expand"], h, k=1), train)
-            y = jax.nn.relu6(y)
-            with jax.named_scope(f"cost:b{i}.dw"):
-                y = _depthwise(blk["dw"], y, stride)
-            y, ns2 = batchnorm(blk["bn2"], bst["bn2"], y, train)
-            y = jax.nn.relu6(y)
-            y, ns3 = batchnorm(blk["bn3"], bst["bn3"],
-                               conv2d(blk["project"], y, k=1), train)
-            h = inp + y if residual else y
-        new_state["blocks"].append({"bn1": ns1, "bn2": ns2, "bn3": ns3})
+            if residual and slu_on:
+                h, nbst, gst, kp, ex = _slu_skip(
+                    lambda h, bst, blk=blk, i=i: _mbv2_branch(
+                        blk, bst, h, 1, train, i),
+                    h, bst, gate_params, gst, i, jnp.bool_(i in ends), e2,
+                    rng)
+            else:
+                inp = h
+                y, nbst = _mbv2_branch(blk, bst, h, stride, train, i)
+                h = inp + y if residual else y
+                kp = ex = jnp.float32(1.0)
+        new_state["blocks"].append(nbst)
+        kps.append(kp)
+        exs.append(ex)
     with jax.named_scope("cost:head"):
         h, ns_head = batchnorm(p["head_bn"], state["head_bn"],
                                conv2d(p["head"], h, k=1), train)
@@ -463,17 +536,19 @@ def mobilenetv2_fwd(p: Params, state: Params, x: jnp.ndarray,
     with jax.named_scope("cost:fc"):
         pooled = jnp.mean(h, axis=(1, 2))
         logits = pooled @ p["fc_w"] + p["fc_b"]
-    return logits, new_state
+    kps_a = jnp.stack(kps)
+    aux = {"slu_cost": jnp.mean(kps_a) if slu_on else jnp.float32(1.0),
+           "slu_executed": jnp.stack(exs), "slu_keep_probs": kps_a}
+    return logits, aux, new_state
 
 
-def mobilenetv2_loss(p: Params, state: Params, batch, rng=None,
+def mobilenetv2_loss(p: Params, state: Params, batch,
+                     e2: Optional[E2TrainConfig] = None, rng=None,
                      train: bool = True):
-    """Task-registry loss contract; MobileNetV2 carries no SLU gate, so the
-    SLU metrics report full execution."""
-    logits, new_state = mobilenetv2_fwd(p, state, batch["image"], train=train)
-    labels = batch["label"]
-    logp = jax.nn.log_softmax(logits)
-    nll = -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=-1))
-    metrics = {"loss": nll, "slu_cost": jnp.float32(1.0),
-               "slu_exec_ratio": jnp.float32(1.0)}
-    return nll, (metrics, new_state)
+    """Cross-entropy + SLU FLOPs regularizer (Eq. 1), as
+    :func:`resnet_loss`: ``slu_cost`` and ``slu_exec_ratio`` are means over
+    all 17 blocks.  Returns ``(total, (metrics, new_state))``."""
+    e2 = e2 or E2TrainConfig()
+    logits, aux, new_state = mobilenetv2_fwd(p, state, batch["image"], e2,
+                                             rng, train=train)
+    return _classifier_loss(logits, aux, batch["label"], e2, new_state)

@@ -23,6 +23,9 @@ Contract:
   experiment's architecture (``core/cost.py``): energy accounting resolves
   through here (``cost_model(exp)``), so it prices what actually trains —
   never transformer math for a CNN (DESIGN.md §Energy).
+* ``depthwise(exp) -> bool`` — whether the experiment's model runs PSG
+  depthwise convs, so the train step reports ``psg_dw_fallback_ratio``
+  (absent means none).
 
 Built-in tasks: ``"lm"`` (the generic transformer stack) and ``"cifar_cnn"``
 (the paper's ResNet-74/110 + MobileNetV2 backbones).
@@ -45,6 +48,7 @@ class Task:
     make_loss: Callable[[Experiment], LossFn]
     make_predict: Optional[Callable[[Experiment], Callable]] = None
     cost: Optional[Callable[[Experiment], TableCostModel]] = None
+    depthwise: Optional[Callable[[Experiment], bool]] = None
 
 
 _REGISTRY: Dict[str, Task] = {}

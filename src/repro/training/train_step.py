@@ -51,16 +51,18 @@ def init_train_state(key, exp: Experiment) -> TrainState:
 
 def make_train_step(exp: Experiment):
     e2, tc = exp.e2, exp.train
-    task_loss = get_task(exp.task).make_loss(exp)
+    task = get_task(exp.task)
+    task_loss = task.make_loss(exp)
     opt = make_optimizer(tc)
     psg_cfg = e2.psg if e2.psg.enabled else None
+    dw_sites = task.depthwise is not None and task.depthwise(exp)
     m = max(tc.microbatches, 1)
 
     def loss_fn(params, model_state, probe, batch, rng):
-        # probe: zeros((2,)) carrier — its gradient accumulates the tile
-        # kernel's [sum fallback_ratio, n_psg_matmuls] across the whole
-        # backward pass (core/psg.py), giving the measured per-step
-        # psg_fallback_ratio without a side channel.
+        # probe: zero_probe() carrier — its gradient accumulates the PSG
+        # kernels' fallback statistics across the whole backward pass
+        # (core/psg.py), giving the measured per-step psg_fallback_ratio
+        # (and psg_dw_fallback_ratio) without a side channel.
         with psgmod.enable(psg_cfg, probe=probe):
             return task_loss(params, model_state, batch, rng)
 
@@ -119,6 +121,11 @@ def make_train_step(exp: Experiment):
             # product.  Only emitted when PSG ran — a baseline step has no
             # measurement, not a measurement of zero.
             metrics["psg_fallback_ratio"] = psgmod.probe_fallback_ratio(probe_g)
+            if dw_sites:
+                # share of depthwise weight-gradient entries whose sign
+                # the full product decided (models with depthwise convs)
+                metrics["psg_dw_fallback_ratio"] = \
+                    psgmod.probe_dw_fallback_ratio(probe_g)
         return TrainState(params, opt_state, swa, state.step + 1,
                           mstate), metrics
 

@@ -109,6 +109,23 @@ def test_fused_conv_kernels_compile(one_chip, kind):
              one_chip, x, g)
 
 
+# MobileNetV2's depthwise convs at batch 128: (input extent, channels,
+# stride): C not a multiple of 128 in one channel tile, stride 2 on taps
+# strided outside the kernel, and C=384 in three 128-lane tiles
+DW_SHAPES = [(32, 144, 1), (8, 576, 2), (8, 384, 1)]
+
+
+@pytest.mark.parametrize("hw, c, stride", DW_SHAPES)
+def test_psg_depthwise_grad_w_compiles(one_chip, hw, c, stride):
+    ho = -(-hw // stride)
+    text = _compile(
+        lambda x, g: ops.psg_depthwise_grad_w(x, g, CFG, stride,
+                                              interpret=False),
+        one_chip, ((128, hw, hw, c), jnp.float32),
+        ((128, ho, ho, c), jnp.float32))
+    assert "s8[9," in text or "psg_depthwise_grad_w" in text
+
+
 def test_strided_conv_grad_x_compiles(one_chip):
     k, s, b, hp, ho, cin, cout = _conv_geometry("strided")
     _compile(lambda a, c: conv.conv_grad_x_pallas(a, c, k=k, stride=s, hp=hp,
